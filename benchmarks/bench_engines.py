@@ -78,7 +78,7 @@ def run(dataset="twin-2k", batch_size=4, days=10, backend="jnp", out=None):
                 hist["cumulative"], ref_hist["cumulative"][:, :Bb],
                 err_msg=f"{label}: trajectory diverged from ensemble")
 
-        # "edges" is the telemetry stat (the in-kernel SMEM counter on the
+        # "edges" is the telemetry stat (the in-kernel counter on the
         # pallas-compact backend, cnt.sum() elsewhere); "contacts" is always
         # the host-side fold. Equality cross-checks the measurement.
         edges = float(np.asarray(hist["edges"], np.int64).sum())

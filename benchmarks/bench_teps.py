@@ -5,7 +5,7 @@ The paper reports 4.6B TEPS for 200 days of the California digital twin on
 interaction backend on whatever hardware runs it, from *measured* traversed
 edges — the per-day edge counters threaded through ``day_step`` — over the
 wall clock of the whole compiled scan. On the ``pallas-compact`` backend the
-edge count comes from the kernel's own SMEM accumulator; every run asserts
+edge count comes from the kernel's own accumulator; every run asserts
 it equals the host-side fold (``contacts``), so the headline number is a
 cross-checked measurement, not an estimate.
 
@@ -64,7 +64,7 @@ def run(dataset="md-mini", days=20,
         _, hist = sim.run1(days)
         edges = int(np.asarray(hist["edges"], np.int64).sum())
         host_edges = int(np.asarray(hist["contacts"], np.int64).sum())
-        # On pallas-compact "edges" is the kernel's SMEM accumulator; it
+        # On pallas-compact "edges" is the kernel's own accumulator; it
         # must equal the host-side fold exactly — else the telemetry lies.
         assert edges == host_edges, (
             f"{backend}: in-kernel edge counter {edges} != "

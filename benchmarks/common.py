@@ -8,6 +8,12 @@ import time
 import numpy as np
 import jax
 
+from repro.launch.cli import use_compile_cache
+
+# Every benchmark script imports this module first: one persistent
+# compilation cache for all of them.
+use_compile_cache()
+
 _ROWS: list[str] = []
 
 

@@ -216,10 +216,7 @@ class recompile_sentinel:
         self._before = 0
 
     def _size(self) -> int:
-        try:
-            return int(self._fn._cache_size())
-        except AttributeError:  # pragma: no cover - older jax
-            return 0
+        return int(self._fn._cache_size())
 
     def __enter__(self):
         self._before = self._size()

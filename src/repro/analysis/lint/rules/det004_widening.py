@@ -13,9 +13,10 @@ source:
   * a **bool mask** (comparison / mask algebra / bool-dtype zeros) —
     its sum is bounded by the shard width, int32 is provably safe;
   * anything else — the sum is unbounded; it must pass through
-    ``.astype(<non-32-bit dtype expr>)`` before the collective. A cast
-    to a *named* dtype (``cdtype``, ``contacts_dtype()``) counts as a
-    deliberate widening decision; a literal ``jnp.int32`` does not.
+    ``.astype(<non-32-bit dtype expr>)`` before the collective, or
+    accumulate in one (``jnp.sum(x, dtype=...)``). A *named* dtype
+    (``cdtype``, ``COUNT_DTYPE``) counts as a deliberate widening
+    decision; a literal ``jnp.int32`` does not.
 """
 
 from __future__ import annotations
@@ -76,8 +77,12 @@ class WideningRule:
                 vals = env.get(src.id, [])
                 if vals and all(is_boolish(v, env) for v in vals):
                     continue
-            # Is the sum wrapped in a widening astype before the collective?
+            # Is the sum wrapped in a widening astype before the collective,
+            # or does it accumulate in a dtype of its own?
             wrapped = self._astype_target(operand, node)
+            if wrapped is None:
+                wrapped = next((kw.value for kw in node.keywords
+                                if kw.arg == "dtype"), None)
             if wrapped is None:
                 yield ctx.finding(
                     self.code, call,

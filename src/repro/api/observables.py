@@ -32,6 +32,7 @@ checkpoint payload in ``repro.api.runner``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -76,6 +77,15 @@ class Observable:
     def finalize(self, carry, ctx: ObsContext) -> dict:
         """Named end-of-run results from the final carry."""
         return {}
+
+    def finalize_series(self, daily, ctx: ObsContext) -> dict:
+        """Named results from the stacked day-major ``daily`` outputs of
+        :meth:`update`; by default the series themselves."""
+        return daily if isinstance(daily, dict) else {"daily": daily}
+
+    def to_host(self, res: dict) -> dict:
+        """The finalized results as host numpy values."""
+        return res
 
 
 @dataclasses.dataclass(frozen=True)
@@ -141,16 +151,32 @@ class EnsembleMeanCI(Observable):
 
     name = "ensemble_mean_ci"
     Z = 1.96
+    KEYS = ("new_infections", "infectious")
 
     def update(self, carry, stats):
-        out = {}
-        for key in ("new_infections", "infectious"):
-            x = stats[key].astype(jnp.float32)
-            B = x.shape[0]  # static
-            m = jnp.mean(x)
-            sem = (jnp.std(x, ddof=1) / np.sqrt(B)) if B > 1 else jnp.float32(0.0)
-            out[key] = {"mean": m, "lo": m - self.Z * sem, "hi": m + self.Z * sem}
-        return carry, out
+        return carry, {k: stats[k] for k in self.KEYS}
+
+    def finalize_series(self, daily, ctx):
+        # The band is computed after the scan, by one compiled program over
+        # the stacked series, whichever path produced them: two programs
+        # that each fuse the float arithmetic differ in its last bit (XLA
+        # turns /B into *(1/B) and may fold it into the next add), and the
+        # post-scan replay must match the in-scan run bit for bit.
+        return {k: _mean_ci_band(daily[k], self.Z) for k in self.KEYS}
+
+
+@functools.partial(jax.jit, static_argnames="z")
+def _mean_ci_band(x, z):
+    """Per-day mean and normal-approximation CI band over the scenario
+    axis of a (days, B) series."""
+    x = x.astype(jnp.float32)
+    B = x.shape[1]  # static
+    m = jnp.mean(x, axis=1)
+    if B > 1:
+        sem = jnp.std(x, axis=1, ddof=1) / np.sqrt(B).astype(np.float32)
+    else:
+        sem = jnp.zeros_like(m)
+    return {"mean": m, "lo": m - z * sem, "hi": m + z * sem}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -201,7 +227,7 @@ class TEPS(Observable):
     plus a running total across days and scenarios — the numerator of the
     paper's headline metric (traversed edges per second; §VI reports 4.6B
     on the California twin). On the pallas-compact backend the per-day
-    counts come from the kernel's in-SMEM accumulator; elsewhere they are
+    counts come from the kernel's own accumulator; elsewhere they are
     host-derived (and everywhere equal to ``stats["contacts"]``, which
     tests assert). The denominator (measured wall clock) is a host-side
     quantity: :func:`repro.api.runner.run` divides it in after the scan."""
@@ -209,18 +235,26 @@ class TEPS(Observable):
     name = "teps"
 
     def init(self, ctx):
-        # Without x64 jnp has no 64-bit ints; f32 keeps the running total
-        # exact below 2^24 edges (plenty for CI-scale runs) and the
-        # day-major int series stays exact regardless.
-        dt = jnp.int64 if jax.config.read("jax_enable_x64") else jnp.float32
-        return jnp.zeros((), dt)
+        # The running total as the (high, low) u32 words of a 64-bit
+        # count: exact at any scale without x64 (no 64-bit ints then), where
+        # an f32 sum stops being exact at 2^24 edges.
+        return jnp.zeros((2,), jnp.uint32)
 
     def update(self, carry, stats):
-        e = stats["edges"]
-        return carry + e.astype(carry.dtype).sum(), {"daily": e}
+        e = stats["edges"].astype(jnp.uint32)
+        hi, lo = carry[0], carry[1]
+        for i in range(e.shape[0]):  # static B
+            nxt = lo + e[i]
+            hi = hi + (nxt < lo).astype(jnp.uint32)  # carry out of lo
+            lo = nxt
+        return jnp.stack([hi, lo]), {"daily": e}
 
     def finalize(self, carry, ctx):
         return {"edges_total": carry}
+
+    def to_host(self, res):
+        hi, lo = (int(w) for w in np.asarray(res["edges_total"]))
+        return {**res, "edges_total": np.int64((hi << 32) | lo)}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -320,7 +354,7 @@ def finalize_all(observables, carries, dailies, ctx: ObsContext) -> dict:
         res = dict(o.finalize(c, ctx))
         d = dailies.get(o.name, ()) if dailies is not None else ()
         if jax.tree.leaves(d):
-            res.update(d if isinstance(d, dict) else {"daily": d})
+            res.update(o.finalize_series(d, ctx))
         out[o.name] = res
     return out
 
@@ -353,4 +387,6 @@ def observe_history(observables, hist, ctx: ObsContext) -> dict:
 
 def observables_to_numpy(obs: dict) -> dict:
     """device pytrees -> host numpy (for RunResult / serialization)."""
-    return jax.tree.map(lambda x: np.asarray(x), jax.device_get(obs))
+    host = jax.tree.map(lambda x: np.asarray(x), jax.device_get(obs))
+    return {name: OBSERVABLES[name]().to_host(res) if name in OBSERVABLES
+            else res for name, res in host.items()}

@@ -160,12 +160,14 @@ def run(spec: ExperimentSpec, *, population=None, chaos=None,
     def make_driver(workers=None):
         """(Re)build the chunk driver — ``workers`` overrides the mesh
         width, the elastic-degradation / repartition rebuild seam."""
+        t = time.time()
         s = spec
         if workers is not None and workers != spec.mesh.workers:
             s = dataclasses.replace(
                 spec, mesh=dataclasses.replace(spec.mesh, workers=workers))
         core = _make_core(engine, s, pop, batch)
         built["core"] = core
+        built["build_s"] = built.get("build_s", 0.0) + time.time() - t
         if not in_scan:
             return engine_lib.SequentialDriver(core)
         return engine_lib.CoreDriver(core, observables)
@@ -232,6 +234,9 @@ def run(spec: ExperimentSpec, *, population=None, chaos=None,
         "jax_backend": jax.default_backend(),
         "wall_s": round(wall, 3),  # end to end, incl. pop build + compile
         "run_wall_s": round(run_wall, 3),  # the day-chunk loop only
+        # engine build (week schedules, params, device transfer), a part
+        # of run_wall_s
+        "engine_build_s": built["build_s"],
         "chunks": num_chunks,
         "chunk_days": ck.every if mgr is not None else spec.days,
         "resumed_from_day": resumed_from,

@@ -19,6 +19,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import tomllib
 from typing import Optional, Tuple
 
 from repro.configs import epidemics as epi_lib
@@ -312,14 +313,4 @@ def _check_fields(cls, d: dict, label: str) -> None:
 
 
 def _load_toml(s: str) -> dict:
-    try:
-        import tomllib  # Python >= 3.11
-    except ImportError:
-        try:
-            import tomli as tomllib  # the pre-3.11 backport
-        except ImportError as e:  # pragma: no cover - both baked into CI image
-            raise ImportError(
-                "TOML specs need tomllib (py>=3.11) or tomli; "
-                "use a JSON spec instead"
-            ) from e
     return tomllib.loads(s)

@@ -100,17 +100,28 @@ def max_occupancy_fast(
     occ = np.zeros((num_locations,), np.int32)
     if E == 0:
         return occ
-    times = np.concatenate([visit_start, visit_end])
-    deltas = np.concatenate([np.ones(E, np.int64), -np.ones(E, np.int64)])
+    assert num_locations < 2**30, num_locations
+    times = np.concatenate([visit_start, visit_end]).astype(np.float32)
+    arrive = np.concatenate([np.ones(E, np.int64), np.zeros(E, np.int64)])
     locs = np.concatenate([visit_loc, visit_loc]).astype(np.int64)
-    # Sort by (loc, time, delta) with departures first at equal times.
-    order = np.lexsort((deltas, times, locs))
-    locs_s, deltas_s = locs[order], deltas[order]
-    run = np.cumsum(deltas_s)
-    # Subtract the cumulative total up to the start of each location segment.
-    seg_start = np.searchsorted(locs_s, np.arange(num_locations), side="left")
-    seg_end = np.searchsorted(locs_s, np.arange(num_locations), side="right")
-    base = np.concatenate([[0], run])[seg_start]
-    # Per-location running max of (run - base) over its segment.
-    np.maximum.at(occ, locs_s, (run - np.repeat(base, seg_end - seg_start)).astype(np.int32))
+    # Sort by (loc, time, delta) with departures first at equal times: one
+    # stable argsort of a packed key orders exactly as that lexsort.
+    key = (locs << 33) | (float32_order_key(times) << 1) | arrive
+    order = np.argsort(key, kind="stable")
+    locs_s = locs[order]
+    run = np.cumsum(2 * arrive[order] - 1)
+    # Subtract the cumulative total up to the start of each location
+    # segment, then take the running max over the segment.
+    seg = np.flatnonzero(np.concatenate([[True], locs_s[1:] != locs_s[:-1]]))
+    base = np.concatenate([[0], run])[seg]
+    rel = run - np.repeat(base, np.diff(np.concatenate([seg, [2 * E]])))
+    occ[locs_s[seg]] = np.maximum.reduceat(rel, seg)
     return occ
+
+
+def float32_order_key(x: np.ndarray) -> np.ndarray:
+    """Non-negative int64 keys that order as the float32 values do (-0.0
+    ties with 0.0), for packing a float into one integer sort key."""
+    bits = (np.asarray(x, np.float32) + np.float32(0.0)).view(np.uint32)
+    flip = np.where(bits >> 31, np.uint32(0xFFFFFFFF), np.uint32(0x80000000))
+    return (bits ^ flip).astype(np.int64)

@@ -64,7 +64,7 @@ def build_week_data(
     shrinks the block-pair schedule NP; layout is epidemiologically free
     (counter-based draws key on ids, not slots)."""
     if pack:
-        week = [pop_lib.pack_day_occupancy(d, block_size) for d in pop.week]
+        week = pop.packed_week(block_size)
         size = max(len(d) for d in week)
         week = [pop_lib.extend_packed(d, size) for d in week]
         extents = [d.extent for d in week]
@@ -141,4 +141,4 @@ def day_exposure(
     A = jax.ops.segment_sum(
         jnp.where(active, acc, 0.0), safe_pid, num_segments=num_people
     ) * jnp.asarray(tau, jnp.float32)  # asarray: tau may be a traced scalar
-    return A, cnt.sum()
+    return A, jnp.sum(cnt, dtype=jnp.uint32)

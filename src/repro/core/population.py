@@ -25,6 +25,13 @@ from repro.core import contact as contact_lib
 
 DAYS_PER_WEEK = 7
 
+#: Most tiles one row block may have in a block-pair schedule. The Pallas
+#: kernel takes the schedule in launches of this many tiles, each ending on
+#: a row-run boundary (kernels/interactions/kernel.py), so a row run must
+#: fit in one. Reaching it would take one location with about four million
+#: visits in a day.
+MAX_ROW_TILES = 1 << 15
+
 
 @dataclasses.dataclass
 class DayVisits:
@@ -65,10 +72,22 @@ class Population:
     contact_prob: np.ndarray  # (L,) f32, from the contact model
     # Weekly schedule
     week: list  # list[DayVisits] of length 7
+    # pack_day_occupancy of every day, per block size: packing is the
+    # costliest step of a build, and preprocess() and the engine share it.
+    _packed: dict = dataclasses.field(
+        default_factory=dict, repr=False, compare=False)
 
     @property
     def visits_per_week(self) -> int:
         return int(sum(d.num_real for d in self.week))
+
+    def packed_week(self, block_size: int) -> list:
+        """The week in the occupancy-packed layout (list[PackedDayVisits]),
+        packed once per block size."""
+        if block_size not in self._packed:
+            self._packed[block_size] = [
+                pack_day_occupancy(d, block_size) for d in self.week]
+        return self._packed[block_size]
 
     def day(self, day_index: int) -> DayVisits:
         return self.week[day_index % DAYS_PER_WEEK]
@@ -124,7 +143,12 @@ def pack_day(
     pad_multiple: int = 128,
 ) -> DayVisits:
     """Sort one day's raw visits by (loc, start) and pad to a static size."""
-    order = np.lexsort((start, loc))
+    # One stable argsort of a packed (loc, start) key: the order of
+    # np.lexsort((start, loc)), in about half the time.
+    loc = np.asarray(loc)
+    assert len(loc) == 0 or (loc.min() >= 0 and loc.max() < 2**31)
+    key = (loc.astype(np.int64) << 32) | contact_lib.float32_order_key(start)
+    order = np.argsort(key, kind="stable")
     person, loc = person[order], loc[order]
     start, end = start[order], end[order]
     n = len(person)
@@ -149,17 +173,26 @@ def pack_day(
 
 
 def pad_week_uniform(week: list, pad_multiple: int = 128) -> list:
-    """Re-pad all 7 days to one common size so a single jit serves the week."""
+    """Re-pad all 7 (already sorted) days to one common size so a single
+    jit serves the week."""
     size = max(len(d) for d in week)
     size = int(np.ceil(size / pad_multiple) * pad_multiple)
-    out = []
-    for d in week:
-        n = d.num_real
-        out.append(
-            pack_day(d.person[:n], d.loc[:n], d.start[:n], d.end[:n], pad_to=size,
-                     pad_multiple=pad_multiple)
+
+    def pad(d):
+        extra = size - len(d)
+        if extra == 0:
+            return d
+        ext = lambda a, fill: np.concatenate([a, np.full(extra, fill, a.dtype)])
+        return DayVisits(
+            person=ext(d.person, -1),
+            loc=ext(d.loc, d.loc[d.num_real - 1] if d.num_real else 0),
+            start=ext(d.start, 0.0),
+            end=ext(d.end, 0.0),
+            active=ext(d.active, False),
+            num_real=d.num_real,
         )
-    return out
+
+    return [pad(d) for d in week]
 
 
 # ----------------------------------------------------------------------------
@@ -205,7 +238,8 @@ def occupancy_pack_order(
     -> input visit index (-1 = alignment padding) for the first ``extent``
     slots.
 
-    Strategy (first-fit decreasing):
+    Strategy (first-fit decreasing, runs by decreasing size, ties in input
+    order):
       * runs with >= block_size visits start on a block boundary, so their
         O((run/b)^2) tile band absorbs no neighbors;
       * the partial tail block of a big run becomes an open bin — small
@@ -213,44 +247,73 @@ def occupancy_pack_order(
         already in the band);
       * remaining small runs are first-fit-decreasing bin-packed into
         whole blocks, so none straddles a boundary.
+
+    First fit is computed a size class at a time. Runs of one size c are
+    placed in input order, and bins only lose room, so every bin passed
+    over stays too full for the rest of the class: the class fills the
+    open bins with room for c in bin order, ``free // c`` runs each, then
+    opens new bins of ``b // c`` runs each. That is the run-at-a-time
+    first fit, slot for slot, at a cost of O(size classes x bins).
     """
     b = block_size
     n = len(loc_sorted)
     if n == 0:
         return np.full((0,), -1, np.int64), 0
     change = np.flatnonzero(np.diff(loc_sorted)) + 1
-    starts = np.concatenate([[0], change])
-    ends = np.concatenate([change, [n]])
-    counts = ends - starts
-    run_order = sorted(range(len(starts)), key=lambda i: (-counts[i], i))
+    starts = np.concatenate([[0], change]).astype(np.int64)
+    counts = np.diff(np.concatenate([starts, [n]]))
+    big = np.flatnonzero(counts >= b)
+    big = big[np.argsort(-counts[big], kind="stable")]
 
-    segments: list[list[int]] = []  # each: run indices, emitted in order
-    bins: list[list[int]] = []  # [segment_index, free_slots]
-    for r in run_order:
-        c = int(counts[r])
-        if c >= b:
-            segments.append([r])
-            free = (-c) % b
-            if free:
-                bins.append([len(segments) - 1, free])
-        else:
-            for entry in bins:
-                if entry[1] >= c:
-                    segments[entry[0]].append(r)
-                    entry[1] -= c
-                    break
-            else:
-                segments.append([r])
-                bins.append([len(segments) - 1, b - c])
+    # Every run gets (segment, placement rank); slots follow that order.
+    seg_of = np.empty(len(starts), np.int64)
+    rank_of = np.empty(len(starts), np.int64)
+    seg_of[big] = np.arange(len(big))
+    rank_of[big] = np.arange(len(big))
+    tail = (-counts[big]) % b
+    has_bin = tail > 0
+    free = tail[has_bin]  # open bins, in creation order
+    bin_seg = np.flatnonzero(has_bin)
+    num_segs, rank = len(big), len(big)
 
-    slot_src: list[int] = []
-    for seg in segments:
-        seg_start = len(slot_src)
-        for r in seg:
-            slot_src.extend(range(int(starts[r]), int(ends[r])))
-        pad = (-(len(slot_src) - seg_start)) % b
-        slot_src.extend([-1] * pad)
-    return np.asarray(slot_src, np.int64), len(slot_src)
+    small_counts = counts[counts < b]
+    for c in np.unique(small_counts)[::-1]:
+        runs = np.flatnonzero(counts == c)  # input order
+        m = len(runs)
+        cand = np.flatnonzero(free >= c)
+        cum = np.cumsum(free[cand] // c)
+        placed = min(m, int(cum[-1]) if len(cum) else 0)
+        where = cand[np.searchsorted(cum, np.arange(placed), side="right")]
+        np.subtract.at(free, where, c)
+        seg_of[runs[:placed]] = bin_seg[where]
+        per = b // c
+        k = np.arange(m - placed) // per
+        num_new = int(k[-1]) + 1 if len(k) else 0
+        seg_of[runs[placed:]] = num_segs + k
+        used = np.bincount(k, minlength=num_new)
+        free = np.concatenate([free, b - c * used])
+        bin_seg = np.concatenate([bin_seg, num_segs + np.arange(num_new)])
+        num_segs += num_new
+        rank_of[runs] = rank + np.arange(m)
+        rank += m
+
+    order = np.lexsort((rank_of, seg_of))  # runs in slot order
+    lens = counts[order]
+    segs = seg_of[order]
+    seg_len = np.bincount(segs, weights=lens, minlength=num_segs).astype(
+        np.int64)
+    pads = (-seg_len) % b
+    # Pieces: each run's visits, then its segment's padding after the
+    # segment's last run.
+    last = np.concatenate([segs[1:] != segs[:-1], [True]])
+    piece_len = np.stack([lens, np.where(last, pads[segs], 0)], 1).ravel()
+    piece_src = np.stack([starts[order], np.full(len(order), -1)], 1).ravel()
+    total = int(piece_len.sum())
+    piece_off = np.cumsum(piece_len) - piece_len
+    src0 = np.repeat(piece_src, piece_len)
+    step = np.arange(total) - np.repeat(piece_off, piece_len)
+    slot_src = np.where(src0 >= 0, src0 + step, -1)
+    return slot_src.astype(np.int64), total
 
 
 def pack_day_occupancy(
@@ -445,22 +508,30 @@ def build_block_schedule(
     V = len(loc_sorted)
     assert V % block_size == 0, (V, block_size)
     nb = V // block_size
-    pairs: set[tuple[int, int]] = set()
     if num_real > 0:
         loc = loc_sorted[:num_real]
-        # Run boundaries of each location segment.
+        # Run boundaries of each location segment; every run adds the full
+        # square of tiles over the blocks it spans.
         change = np.flatnonzero(np.diff(loc)) + 1
-        starts = np.concatenate([[0], change])
-        ends = np.concatenate([change, [num_real]])
-        for s, e in zip(starts, ends):
-            b0, b1 = s // block_size, (e - 1) // block_size
-            for rb in range(b0, b1 + 1):
-                for cb in range(b0, b1 + 1):
-                    pairs.add((rb, cb))
-    if not pairs:
-        pairs.add((0, 0))
-    arr = np.array(sorted(pairs), np.int32)
+        starts = np.concatenate([[0], change]).astype(np.int64)
+        ends = np.concatenate([change, [num_real]]).astype(np.int64)
+        b0 = starts // block_size
+        span = (ends - 1) // block_size - b0 + 1
+        area = span * span
+        run = np.repeat(np.arange(len(starts)), area)
+        idx = np.arange(int(area.sum())) - np.repeat(np.cumsum(area) - area,
+                                                     area)
+        rb = b0[run] + idx // span[run]
+        cb = b0[run] + idx % span[run]
+        key = np.unique(rb * nb + cb)  # sorted: row-major
+        arr = np.stack([key // nb, key % nb], 1).astype(np.int32)
+    else:
+        arr = np.zeros((1, 2), np.int32)  # a (0, 0) tile keeps shapes static
     num_pairs = len(arr)
+    row_tiles = np.bincount(arr[:, 0]).max()
+    assert row_tiles <= MAX_ROW_TILES, (
+        f"a row block has {row_tiles} tiles; the kernel takes at most "
+        f"{MAX_ROW_TILES} per row")
     pair_active = np.ones((num_pairs,), np.bool_)
     if pad_to is not None and pad_to > num_pairs:
         # Pad by repeating the final pair with active=False. The repeat keeps
@@ -473,12 +544,8 @@ def build_block_schedule(
             [pair_active, np.zeros((pad_to - num_pairs,), np.bool_)]
         )
     row_block, col_block = arr[:, 0].copy(), arr[:, 1].copy()
-    row_start = np.zeros((len(arr),), np.bool_)
-    seen: set[int] = set()
-    for k in range(len(arr)):
-        if pair_active[k] and int(row_block[k]) not in seen:
-            row_start[k] = True
-            seen.add(int(row_block[k]))
+    row_start = np.concatenate(
+        [[True], row_block[1:] != row_block[:-1]]) & pair_active
     return BlockSchedule(
         block_size=block_size,
         num_blocks=nb,
@@ -495,16 +562,10 @@ def week_packing_stats(pop: "Population", block_size: int) -> dict:
     week: total block-pair tiles (NP) and padded visit-slot counts before
     and after :func:`pack_day_occupancy`, summed over the 7 days."""
     np_before = np_after = v_before = v_after = 0
-    for d in pop.week:
-        n = d.num_real
-        base = pack_day(
-            d.person[:n], d.loc[:n], d.start[:n], d.end[:n],
-            pad_multiple=block_size,
-        )
-        packed = pack_day_occupancy(base, block_size)
+    for d, packed in zip(pop.week, pop.packed_week(block_size)):
         np_before += packed.np_before
         np_after += packed.np_after
-        v_before += len(base)
+        v_before += -(-max(d.num_real, 1) // block_size) * block_size
         v_after += len(packed)
     return {
         "block_size": block_size,

@@ -80,7 +80,10 @@ def uniform(seed, *words):
     """U(0,1) float32 from the hash; never exactly 0 (safe for log)."""
     h = hash_u32(seed, *words)
     # Top 24 bits -> [0, 1) with 2^-24 resolution, then offset by 2^-25.
-    u = (h >> np.uint32(8)).astype(jnp.float32) * jnp.float32(2.0**-24)
+    # The value is below 2^24, so going through int32 is exact, and Mosaic
+    # has no u32 -> f32 conversion.
+    u = (h >> np.uint32(8)).astype(jnp.int32).astype(jnp.float32) \
+        * jnp.float32(2.0**-24)
     return u + jnp.float32(2.0**-25)
 
 

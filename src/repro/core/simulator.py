@@ -69,6 +69,12 @@ STAT_KEYS = ("day", "new_infections", "cumulative", "infectious",
              # emits the zeros; the unified engine computes them).
              "tests_used", "isolated", "traced")
 
+#: dtype of the daily "contacts"/"edges" counts. They are non-negative, so
+#: unsigned 32 bits give twice int32's range with or without x64: md's
+#: daily count is below 10^9 (visits x at most ~40 contacts per visit) and
+#: wraps at 2^32. Totals over days are summed exactly by TEPS.
+COUNT_DTYPE = jnp.uint32
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
@@ -258,19 +264,16 @@ def phase_update(static, params, state, A, contacts, vaccinated):
     new_count = new_mask.sum().astype(jnp.int32)
     cumulative = state.cumulative + new_count
     infectious = (params.inf_table[health] > 0.0).sum().astype(jnp.int32)
-    cdtype = (
-        jnp.int64 if jax.config.read("jax_enable_x64") else jnp.int32
-    )
     stats = {
         "day": state.day,
         "new_infections": new_count,
         "cumulative": cumulative,
         "infectious": infectious,
         "susceptible": (params.sus_table[health] > 0.0).sum().astype(jnp.int32),
-        "contacts": contacts.astype(cdtype),
+        "contacts": contacts.astype(COUNT_DTYPE),
         # Host-side traversed edges; the unified engine substitutes the
         # in-kernel counter on the pallas-compact backend.
-        "edges": contacts.astype(cdtype),
+        "edges": contacts.astype(COUNT_DTYPE),
         # The reference path carries no per-agent interventions; the stats
         # are constant zeros (the engine must match them bitwise whenever
         # no TestTraceIsolate slot is configured).

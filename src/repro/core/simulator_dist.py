@@ -508,11 +508,9 @@ def dist_day_step(
     susceptible = jax.lax.psum(
         (params.sus_table[health] > 0.0).sum().astype(jnp.int32), axis
     )
-    # Widen before the cross-worker accumulation: at paper scale (~4.6B
-    # traversed edges/s) an int32 psum wraps within one day. Mirrors the
-    # single-device widening in simulator.py:phase_update.
-    cdtype = jnp.int64 if jax.config.read("jax_enable_x64") else jnp.int32
-    contacts = jax.lax.psum(cnt.sum().astype(cdtype), axis)
+    # Summed unsigned before the cross-worker accumulation (see
+    # simulator.COUNT_DTYPE).
+    contacts = jax.lax.psum(jnp.sum(cnt, dtype=sim_lib.COUNT_DTYPE), axis)
     stats = {
         "day": day,
         "new_infections": new_count,

@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.sweep import Scenario, ScenarioBatch
-from repro.core import compat
 from repro.engine.cache import BoundedLRU
 from repro.core import interactions as inter_lib
 from repro.core import interventions as iv_lib
@@ -414,13 +413,14 @@ class EngineCore:
         else:
             pspec, sspec, wspec, hspec = self._specs
             runner = jax.jit(
-                compat.shard_map(
+                jax.shard_map(
                     worker,
                     mesh=self.mesh,
                     # carries/dailies ride replicated: every shard sees the
                     # full gathered stats, so their reductions are identical.
                     in_specs=(pspec, sspec, P(), wspec, wspec),
                     out_specs=(sspec, P(), hspec, P()),
+                    check_vma=False,
                 )
             )
         self._runners.put(key, runner)

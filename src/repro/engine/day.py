@@ -245,10 +245,9 @@ def day_step(
     susceptible = topo.psum(
         (params.sus_table[health] > 0.0).sum().astype(jnp.int32)
     )
-    # Widen before the cross-worker accumulation: at paper scale an int32
-    # contacts psum wraps within one day.
-    cdtype = jnp.int64 if jax.config.read("jax_enable_x64") else jnp.int32
-    contacts = topo.psum(cnt.sum().astype(cdtype))
+    # Summed unsigned before the cross-worker accumulation (see
+    # simulator.COUNT_DTYPE).
+    contacts = topo.psum(jnp.sum(cnt, dtype=sim_lib.COUNT_DTYPE))
 
     # ---- per-agent state advance (result-latency TTI semantics) ----------
     if K2:
@@ -289,11 +288,11 @@ def day_step(
         "susceptible": susceptible,
         "contacts": contacts,
         # Traversed-edge counter (TEPS numerator). On pallas-compact this
-        # is the kernel's SMEM accumulator; elsewhere it is cnt.sum() —
+        # is the kernel's own accumulator; elsewhere it is cnt.sum() —
         # both equal `contacts` exactly, which tests assert, making the
         # in-kernel telemetry a cross-checked measurement rather than a
         # trusted one.
-        "edges": topo.psum(edges.astype(cdtype)),
+        "edges": topo.psum(edges.astype(sim_lib.COUNT_DTYPE)),
         "tests_used": tests_used,
         "isolated": isolated,
         "traced": traced_new,

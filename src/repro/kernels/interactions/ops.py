@@ -15,8 +15,9 @@ Six interchangeable implementations, all bitwise-identical in output
                               stable sort) and runs a fori_loop bounded by
                               the *traced* live count, so a day with 0.1%
                               live tiles costs ~0.1% of the tile work.
-  interactions_pallas         the TPU kernel (kernel.py); compiled on TPU,
-                              interpret mode elsewhere (auto-detected).
+  interactions_pallas         the TPU kernel (kernel.py) over the padded
+                              schedule; compiled on TPU, interpret mode on
+                              CPU, refused elsewhere.
   interactions_pallas_compact the fused kernel: the compact backend's
                               schedule compaction feeding the Pallas kernel
                               directly via scalar prefetch, with an
@@ -59,8 +60,9 @@ import jax.numpy as jnp
 from repro.kernels.interactions.kernel import (
     interactions_pallas_call,
     interactions_pallas_compact_call,
+    live_tiles,
 )
-from repro.kernels.interactions.ref import pair_tile, pair_tile_traced
+from repro.kernels.interactions.ref import blocked_tile
 
 
 def _block_any_positive(val, pid, num_blocks, block_size):
@@ -80,16 +82,6 @@ def row_has_susceptible(sus_val, pid, num_blocks, block_size):
     susceptible-heavy (col_has_inf kills most tiles), late days are the
     mirror case (row_has_sus kills them)."""
     return _block_any_positive(sus_val, pid, num_blocks, block_size)
-
-
-def live_tiles(row_idx, col_idx, pair_active, col_has_inf, row_has_sus):
-    """The per-tile liveness predicate shared by every backend: scheduled,
-    not padding, and with both an infectious column and susceptible row."""
-    return (
-        (pair_active == 1)
-        & (col_has_inf[col_idx] > 0)
-        & (row_has_sus[row_idx] > 0)
-    )
 
 
 def _gather_block(arr, blk, b):
@@ -115,13 +107,9 @@ def interactions_blocked_jnp(
         cols = [_gather_block(a, cb, b) for a in (pid, loc, start, end, inf_val)]
         # Masked (padding or short-circuited) pairs contribute zero; the
         # flops still run — this is the no-skip vectorized variant.
-        if src_val is None:
-            rho, cnt = pair_tile(seed, day, *rows, *cols)
-            return jnp.where(live, rho, 0.0), jnp.where(live, cnt, 0)
-        src = _gather_block(src_val, cb, b)
-        rho, cnt, trc = pair_tile_traced(seed, day, *rows, *cols, src)
-        return (jnp.where(live, rho, 0.0), jnp.where(live, cnt, 0),
-                jnp.where(live, trc, 0))
+        src = None if src_val is None else _gather_block(src_val, cb, b)
+        tile = blocked_tile(seed, day, rows, cols, src)
+        return tuple(jnp.where(live, t, jnp.zeros((), t.dtype)) for t in tile)
 
     live = live_tiles(row_idx, col_idx, pair_active, col_has_inf, row_has_sus)
     outs = jax.vmap(one_pair)(row_idx, col_idx, live)
@@ -156,11 +144,8 @@ def interactions_blocked_scan(
         def body(_):
             rows = [_gather_block(a, rb, b) for a in (pid, loc, start, end, p_loc, sus_val)]
             cols = [_gather_block(a, cb, b) for a in (pid, loc, start, end, inf_val)]
-            if src_val is None:
-                tile = pair_tile(seed, day, *rows, *cols)
-            else:
-                src = _gather_block(src_val, cb, b)
-                tile = pair_tile_traced(seed, day, *rows, *cols, src)
+            src = None if src_val is None else _gather_block(src_val, cb, b)
+            tile = blocked_tile(seed, day, rows, cols, src)
             return tuple(_upd(a, rb, t) for a, t in zip(carry, tile))
 
         def skip(_):
@@ -221,11 +206,8 @@ def interactions_compact(
         rb, cb = rows_c[k], cols_c[k]
         rows = [_gather_block(a, rb, b) for a in (pid, loc, start, end, p_loc, sus_val)]
         cols = [_gather_block(a, cb, b) for a in (pid, loc, start, end, inf_val)]
-        if src_val is None:
-            tile = pair_tile(seed, day, *rows, *cols)
-        else:
-            src = _gather_block(src_val, cb, b)
-            tile = pair_tile_traced(seed, day, *rows, *cols, src)
+        src = None if src_val is None else _gather_block(src_val, cb, b)
+        tile = blocked_tile(seed, day, rows, cols, src)
         return tuple(_upd(a, rb, t) for a, t in zip(carry, tile))
 
     acc0 = jnp.zeros((V,), jnp.float32)
@@ -236,104 +218,46 @@ def interactions_compact(
     return jax.lax.fori_loop(0, n_live, body, carry0)
 
 
-def interactions_pallas(
-    pid, loc, start, end, p_loc, sus_val, inf_val,
-    row_idx, col_idx, row_start, pair_active, col_has_inf, row_has_sus,
-    meta,
-    *,
-    block_size: int,
-    interpret: bool | None = None,
-    src_val=None,
-):
-    """Pallas path. ``interpret=None`` auto-detects: compiled on TPU,
-    interpreter everywhere else (the interpreter is the correctness path on
-    CPU CI; the compiled kernel is the perf target)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    outs = interactions_pallas_call(
-        pid, loc, start, end, p_loc, sus_val, inf_val,
-        row_idx, col_idx, row_start, pair_active, col_has_inf, row_has_sus,
-        meta,
-        block_size=block_size, interpret=interpret, src_val=src_val,
-    )
-    # Row blocks no schedule tile maps to are never written by the kernel
-    # (their VMEM output block is never brought in), so their contents are
-    # undefined; zero them to honor the shared backend contract. All-padding
-    # blocks at the tail of short days hit this.
-    nb = pid.shape[0] // block_size
-    visited = jnp.zeros((nb,), jnp.int32).at[row_idx].max(
-        pair_active.astype(jnp.int32)
-    )
-    mask = jnp.repeat(visited > 0, block_size)
-    return tuple(
-        jnp.where(mask, o, jnp.zeros((), o.dtype)) for o in outs
+def pallas_interpret() -> bool:
+    """Whether the Pallas kernels run in interpret mode: compiled on TPU,
+    interpreted on CPU (the test path), and refused anywhere else — a
+    device that is neither must not be measured through the interpreter."""
+    platform = jax.default_backend()
+    if platform not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the Pallas interaction kernels run on 'tpu' (compiled) or "
+            f"'cpu' (interpreted), not on '{platform}'")
+    return platform == "cpu"
+
+
+_PALLAS_CALLS = {
+    "pallas": interactions_pallas_call,
+    "pallas-compact": interactions_pallas_compact_call,
+}
+
+
+def _pallas_full(backend, *args, block_size: int, src_val=None):
+    """The kernel's outputs: (acc, cnt, edges), or (acc, cnt, trc, edges)
+    with ``src_val``. ``edges`` is the in-kernel traversed-edge count, the
+    sum of contact counts over live tiles, i.e. exactly ``cnt.sum()``."""
+    return _PALLAS_CALLS[backend](
+        *args, block_size=block_size, interpret=pallas_interpret(),
+        src_val=src_val,
     )
 
 
-def _pallas_compact_full(
-    pid, loc, start, end, p_loc, sus_val, inf_val,
-    row_idx, col_idx, row_start, pair_active, col_has_inf, row_has_sus,
-    meta,
-    *,
-    block_size: int,
-    interpret: bool | None = None,
-    src_val=None,
-):
-    """Fused active-set Pallas path; returns (acc, cnt, edges) — or
-    (acc, cnt, trc, edges) when ``src_val`` is given.
-
-    Compaction happens here, inside jit, with the *same* stable sort as
-    ``interactions_compact`` — live tiles to the schedule front in original
-    row-major order — and the compacted arrays plus the traced live count
-    are scalar-prefetched into the kernel, whose grid steps past the live
-    prefix clamp their index maps (no DMA, no flops). Accumulation order is
-    therefore identical to `compact`, which is identical to `jnp` (dead
-    tiles contribute exact +0.0) — bitwise equality by construction.
-
-    ``edges`` is the in-kernel traversed-edge scalar: the sum of contact
-    counts over live tiles, i.e. exactly ``cnt.sum()`` of the masked output.
-    """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    b = block_size
-    nb = pid.shape[0] // b
-
-    live = live_tiles(row_idx, col_idx, pair_active, col_has_inf, row_has_sus)
-    # Stable partition: live tiles first, original (row-major) order kept.
-    order = jnp.argsort(jnp.where(live, 0, 1), stable=True)
-    rows_c = row_idx[order].astype(jnp.int32)
-    cols_c = col_idx[order].astype(jnp.int32)
-    n_live = live.sum().astype(jnp.int32).reshape(1)
-    # Recompute row-run starts for the compacted order (live tiles of one
-    # row block stay consecutive, so a change of row index marks a run).
-    prev = jnp.concatenate([rows_c[:1] - 1, rows_c[:-1]])
-    row_start_c = (rows_c != prev).astype(jnp.int32)
-
-    outs = interactions_pallas_compact_call(
-        pid, loc, start, end, p_loc, sus_val, inf_val,
-        rows_c, cols_c, row_start_c, n_live, col_has_inf, row_has_sus,
-        meta,
-        block_size=block_size, interpret=interpret, src_val=src_val,
-    )
-    *per_visit, edges = outs
-    # Row blocks with no *live* tile are never brought into VMEM, so their
-    # output is undefined; zero them (the fused analog of the padded
-    # kernel's visited mask — stricter, since liveness implies visited).
-    visited = jnp.zeros((nb,), jnp.int32).at[row_idx].max(
-        live.astype(jnp.int32)
-    )
-    mask = jnp.repeat(visited > 0, b)
-    masked = tuple(
-        jnp.where(mask, o, jnp.zeros((), o.dtype)) for o in per_visit
-    )
-    return masked + (edges,)
+def interactions_pallas(*args, **kwargs):
+    """The kernel over the padded schedule (kernel.py): one grid step per
+    scheduled tile, dead tiles skipped in the kernel."""
+    return tuple(_pallas_full("pallas", *args, **kwargs)[:-1])
 
 
 def interactions_pallas_compact(*args, **kwargs):
-    """BACKENDS-contract view of the fused kernel: the per-visit outputs
-    only — (acc, cnt), plus trc when ``src_val`` is given."""
-    *per_visit, _ = _pallas_compact_full(*args, **kwargs)
-    return tuple(per_visit)
+    """The fused active-set kernel: the compact backend's schedule
+    compaction (the *same* stable sort, so accumulation order — and every
+    f32 bit — matches `compact` and `jnp`) feeding the kernel, whose grid
+    steps past the live prefix issue no DMA and no flops."""
+    return tuple(_pallas_full("pallas-compact", *args, **kwargs)[:-1])
 
 
 BACKENDS = {
@@ -344,45 +268,35 @@ BACKENDS = {
     "pallas-compact": interactions_pallas_compact,
 }
 
-_PALLAS_BACKENDS = ("pallas", "pallas-compact")
 
-
-def interactions_auto(*args, backend: str = "jnp", interpret: bool | None = None,
-                      **kwargs):
+def interactions_auto(*args, backend: str = "jnp", **kwargs):
     """Dispatch by backend name.
 
     'jnp' is the dense-throughput CPU default, 'compact' the active-set
-    engine (work ∝ live epidemic activity), 'pallas' the TPU target
-    (compiled there, interpret mode elsewhere — override via ``interpret``)
-    and 'pallas-compact' the fused active-set kernel (compaction + tile
-    math + edge telemetry in one launch).
+    engine (work ∝ live epidemic activity), 'pallas' the TPU kernel over
+    the padded schedule and 'pallas-compact' the fused active-set kernel
+    (compaction + tile math + edge telemetry). The Pallas kernels compile
+    on TPU and run in interpret mode on CPU (:func:`pallas_interpret`).
     """
-    if backend in _PALLAS_BACKENDS:
-        return BACKENDS[backend](*args, interpret=interpret, **kwargs)
     return BACKENDS[backend](*args, **kwargs)
 
 
-def interactions_auto_edges(*args, backend: str = "jnp",
-                            interpret: bool | None = None, **kwargs):
+def interactions_auto_edges(*args, backend: str = "jnp", **kwargs):
     """Like ``interactions_auto`` but also returns the traversed-edge count
     (i32 scalar) — the TEPS numerator.
 
-    For 'pallas-compact' the count comes from the in-kernel SMEM
-    accumulator; every other backend derives it on the host side as
-    ``cnt.sum()``. Both are sums of the same live-tile contact counts, so
-    the two routes agree exactly (asserted in tests/test_interactions.py).
+    For the Pallas backends the count comes from the in-kernel
+    accumulator; every other backend derives it as ``cnt.sum()``. Both are
+    sums of the same live-tile contact counts, so the two routes agree
+    exactly (asserted in tests/test_interactions.py).
     """
-    if backend == "pallas-compact":
-        return _pallas_compact_full(*args, interpret=interpret, **kwargs)
-    if backend == "pallas":
-        acc, cnt = BACKENDS[backend](*args, interpret=interpret, **kwargs)
-    else:
-        acc, cnt = BACKENDS[backend](*args, **kwargs)
+    if backend in _PALLAS_CALLS:
+        return _pallas_full(backend, *args, **kwargs)
+    acc, cnt = BACKENDS[backend](*args, **kwargs)
     return acc, cnt, cnt.sum().astype(jnp.int32)
 
 
-def interactions_auto_traced(*args, backend: str = "jnp",
-                             interpret: bool | None = None, src_val=None,
+def interactions_auto_traced(*args, backend: str = "jnp", src_val=None,
                              **kwargs):
     """Traced twin of ``interactions_auto_edges``: runs the interaction
     pass with the second (contact-tracing) accumulator enabled and returns
@@ -392,18 +306,13 @@ def interactions_auto_traced(*args, backend: str = "jnp",
     visitor tested positive today); ``trc`` is the per-visit count of
     traced contacts, accumulated tile-for-tile alongside ``acc``/``cnt``
     so it is bitwise identical across all five backends. ``edges`` keeps
-    its meaning (and, on 'pallas-compact', its in-kernel SMEM route).
+    its meaning (and, on the Pallas backends, its in-kernel route).
     """
     assert src_val is not None
-    if backend == "pallas-compact":
-        acc, cnt, trc, edges = _pallas_compact_full(
-            *args, interpret=interpret, src_val=src_val, **kwargs
+    if backend in _PALLAS_CALLS:
+        acc, cnt, trc, edges = _pallas_full(
+            backend, *args, src_val=src_val, **kwargs
         )
         return acc, cnt, edges, trc
-    if backend == "pallas":
-        acc, cnt, trc = BACKENDS[backend](
-            *args, interpret=interpret, src_val=src_val, **kwargs
-        )
-    else:
-        acc, cnt, trc = BACKENDS[backend](*args, src_val=src_val, **kwargs)
+    acc, cnt, trc = BACKENDS[backend](*args, src_val=src_val, **kwargs)
     return acc, cnt, cnt.sum().astype(jnp.int32), trc

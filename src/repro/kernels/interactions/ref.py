@@ -36,43 +36,60 @@ def contact_uniform(seed, day, pid_i, pid_j, loc):
     )
 
 
+def fold_cols(x):
+    """Sum a (C, R) tile over its column axis in one fixed order: halve the
+    axis and add the two halves until one row is left. The order is spelled
+    out as elementwise adds, so XLA (any backend) and Mosaic produce the
+    same f32 bits, which a backend-chosen reduction tree does not promise.
+    Returns (1, R)."""
+    n = x.shape[0]
+    while n > 1 and n % 2 == 0:
+        n //= 2
+        x = x[:n] + x[n:]
+    if n > 1:  # only the dense oracle's odd widths reach this
+        x = jnp.sum(x, axis=0, keepdims=True)
+    return x
+
+
+def _pair_terms(seed, day, pid_r, loc_r, start_r, end_r, p_r, sus_r,
+                pid_c, loc_c, start_c, end_c, inf_c):
+    overlap = jnp.maximum(
+        jnp.minimum(end_r, end_c) - jnp.maximum(start_r, start_c), 0.0
+    )
+    valid = (
+        (pid_r >= 0)
+        & (pid_c >= 0)
+        & (loc_r == loc_c)
+        & (pid_r != pid_c)
+        & (overlap > 0.0)
+    )
+    u = contact_uniform(seed, day, pid_r, pid_c, loc_r)
+    contact = valid & (u < p_r)
+    rho = overlap * sus_r * inf_c * contact.astype(jnp.float32)
+    pair = contact & (sus_r > 0.0) & (inf_c > 0.0)
+    return rho, pair
+
+
 def pair_tile(
     seed,
     day,
     pid_r, loc_r, start_r, end_r, p_r, sus_r,  # row side (susceptible)
     pid_c, loc_c, start_c, end_c, inf_c,  # col side (infectious)
 ):
-    """Compute one (R, C) tile of propensities and contact counts.
+    """Compute one tile of propensities and contact counts.
 
     Shared verbatim by the dense oracle, the blocked jnp paths, and the
-    Pallas kernel body — a single source of truth for the pair math.
-    Returns (rho_rowsum (R,), contact_count_rowsum (R,) int32).
+    Pallas kernel body — a single source of truth for the pair math. The
+    tile is laid out (C, R): row-side operands broadcast along axis 0
+    (shape (1, R), or (C, R)), col-side operands along axis 1 ((C, 1), or
+    (C, R)), so the per-row sums come out lane-major as (1, R).
+    Returns (rho_rowsum (1, R) f32, contact_count_rowsum (1, R) int32).
     """
-    overlap = jnp.maximum(
-        jnp.minimum(end_r[:, None], end_c[None, :])
-        - jnp.maximum(start_r[:, None], start_c[None, :]),
-        0.0,
-    )
-    active_r = pid_r >= 0
-    active_c = pid_c >= 0
-    valid = (
-        active_r[:, None]
-        & active_c[None, :]
-        & (loc_r[:, None] == loc_c[None, :])
-        & (pid_r[:, None] != pid_c[None, :])
-        & (overlap > 0.0)
-    )
-    u = contact_uniform(seed, day, pid_r[:, None], pid_c[None, :], loc_r[:, None])
-    contact = valid & (u < p_r[:, None])
-    rho = overlap * sus_r[:, None] * inf_c[None, :] * contact.astype(jnp.float32)
-    cnt = (
-        contact & (sus_r[:, None] > 0.0) & (inf_c[None, :] > 0.0)
-    ).astype(jnp.int32)
-    # Pin the rowsum to int32: under JAX_ENABLE_X64 an int32 sum promotes
-    # to int64 (numpy semantics) and would clash with the backends' int32
-    # accumulators. A tile rowsum cannot overflow int32; the day step
-    # widens to int64 *before* the cross-worker contacts psum (PR 2).
-    return rho.sum(axis=1), cnt.sum(axis=1).astype(jnp.int32)
+    rho, pair = _pair_terms(seed, day, pid_r, loc_r, start_r, end_r, p_r,
+                            sus_r, pid_c, loc_c, start_c, end_c, inf_c)
+    # A tile rowsum cannot overflow int32; the day step widens before the
+    # cross-worker contacts psum.
+    return fold_cols(rho), fold_cols(pair.astype(jnp.int32))
 
 
 def pair_tile_traced(
@@ -90,43 +107,39 @@ def pair_tile_traced(
     it so regardless), so tiles that are dead for the exposure accumulator
     are dead for tracing *by algebra* — no extra masking, and the same
     skip/mask bitwise-equality argument the backends rely on carries over.
-    Returns (rho_rowsum (R,), cnt_rowsum (R,) i32, trc_rowsum (R,) i32).
+    Returns (rho_rowsum, cnt_rowsum i32, trc_rowsum i32), each (1, R).
     """
-    overlap = jnp.maximum(
-        jnp.minimum(end_r[:, None], end_c[None, :])
-        - jnp.maximum(start_r[:, None], start_c[None, :]),
-        0.0,
-    )
-    active_r = pid_r >= 0
-    active_c = pid_c >= 0
-    valid = (
-        active_r[:, None]
-        & active_c[None, :]
-        & (loc_r[:, None] == loc_c[None, :])
-        & (pid_r[:, None] != pid_c[None, :])
-        & (overlap > 0.0)
-    )
-    u = contact_uniform(seed, day, pid_r[:, None], pid_c[None, :], loc_r[:, None])
-    contact = valid & (u < p_r[:, None])
-    rho = overlap * sus_r[:, None] * inf_c[None, :] * contact.astype(jnp.float32)
-    pair = contact & (sus_r[:, None] > 0.0) & (inf_c[None, :] > 0.0)
-    cnt = pair.astype(jnp.int32)
-    trc = (pair & (src_c[None, :] > 0.0)).astype(jnp.int32)
+    rho, pair = _pair_terms(seed, day, pid_r, loc_r, start_r, end_r, p_r,
+                            sus_r, pid_c, loc_c, start_c, end_c, inf_c)
+    trc = pair & (src_c > 0.0)
     return (
-        rho.sum(axis=1),
-        cnt.sum(axis=1).astype(jnp.int32),
-        trc.sum(axis=1).astype(jnp.int32),
+        fold_cols(rho),
+        fold_cols(pair.astype(jnp.int32)),
+        fold_cols(trc.astype(jnp.int32)),
     )
+
+
+def blocked_tile(seed, day, rows, cols, src=None):
+    """`pair_tile` (or, with ``src``, `pair_tile_traced`) on 1-D (b,)
+    blocks, as the jnp backends hold them: rows become (1, b), columns
+    (b, 1), and the (1, b) sums come back as (b,)."""
+    r = [a[None, :] for a in rows]
+    c = [a[:, None] for a in cols]
+    if src is None:
+        out = pair_tile(seed, day, *r, *c)
+    else:
+        out = pair_tile_traced(seed, day, *r, *c, src[:, None])
+    return tuple(o[0] for o in out)
 
 
 def interactions_dense(
     pid, loc, start, end, p_loc, sus_val, inf_val, seed, day
 ):
     """Dense all-pairs oracle. Returns (acc (V,), contacts (V,))."""
-    return pair_tile(
+    return blocked_tile(
         seed, day,
-        pid, loc, start, end, p_loc, sus_val,
-        pid, loc, start, end, inf_val,
+        (pid, loc, start, end, p_loc, sus_val),
+        (pid, loc, start, end, inf_val),
     )
 
 
@@ -135,8 +148,8 @@ def interactions_dense_traced(
 ):
     """Dense oracle with the tracing accumulator.
     Returns (acc (V,), contacts (V,), traced (V,))."""
-    return pair_tile_traced(
+    return blocked_tile(
         seed, day,
-        pid, loc, start, end, p_loc, sus_val,
-        pid, loc, start, end, inf_val, src_val,
+        (pid, loc, start, end, p_loc, sus_val),
+        (pid, loc, start, end, inf_val), src_val,
     )

@@ -15,9 +15,30 @@ Without ``--spec``, the driver's own defaults fill the gaps.
 from __future__ import annotations
 
 import argparse
+import os
+import pathlib
+
+import jax
 
 from repro.api.spec import BACKENDS, ENGINES, ExperimentSpec
 from repro.configs.presets import DISEASES, INTERVENTION_PRESETS
+
+#: The checkout this package runs from (src/repro/launch/cli.py -> root).
+CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
+    reads it itself and nothing is set here; otherwise the cache is the
+    fixed ``<checkout>/.jax_cache`` (git-ignored), never a temporary or
+    per-run path, so that a later run finds what this one compiled."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def add_common_args(ap: argparse.ArgumentParser) -> argparse.ArgumentParser:

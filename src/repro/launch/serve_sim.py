@@ -34,6 +34,7 @@ from concurrent.futures import ThreadPoolExecutor
 from repro.launch.cli import (
     add_common_args,
     build_spec,
+    use_compile_cache,
     parse_intervention_axis,
 )
 from repro.serve import ServeConfig, SimulationServer
@@ -187,6 +188,7 @@ def main():
                     help="exit non-zero on recompile violations or failed "
                          "requests")
     args = ap.parse_args()
+    use_compile_cache()
 
     extra = {}
     if args.interventions:

@@ -36,6 +36,7 @@ def main():
     ap.add_argument("--sharded", action="store_true",
                     help="force sharding the scenario axis over all devices")
     args = ap.parse_args()
+    cli.use_compile_cache()
 
     extra = {}
     if args.interventions is not None:

@@ -109,11 +109,9 @@ def moe_ffn_dispatch(x, p, cfg, rules=None):
         aux = {k: _jax.lax.pmean(v, manual) for k, v in aux.items()}
         return out, aux
 
-    from repro.core import compat
-
-    return compat.shard_map(
+    return _jax.shard_map(
         inner, mesh=mesh, in_specs=(bspec, P()), out_specs=(bspec, P()),
-        axis_names=set(manual),
+        axis_names=set(manual), check_vma=False,
     )(x, p)
 
 

@@ -10,4 +10,5 @@ def day_counts(contacts, infected, cdtype):
     bounded = jax.lax.psum(mask.sum().astype(jnp.int32), "workers")
     widened = jax.lax.psum(contacts.sum().astype(cdtype), "workers")
     wide64 = jax.lax.psum(contacts.sum().astype(jnp.int64), "workers")
-    return bounded, widened, wide64
+    summed_wide = jax.lax.psum(jnp.sum(contacts, dtype=cdtype), "workers")
+    return bounded, widened, wide64, summed_wide

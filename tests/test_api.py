@@ -332,3 +332,43 @@ def test_run_result_json_roundtrip(pop, tmp_path):
     # legacy fallback path: strip the observables, rows still come out
     back.observables = {}
     assert summarize_result(back) == r.summaries
+
+
+def test_teps_total_is_exact_beyond_float32():
+    """The TEPS running total is exact far past 2^24 (where an f32 sum
+    starts rounding) and past 2^32 (where one u32 word wraps), against the
+    int64 sum of the daily series."""
+    rs = np.random.default_rng(0)
+    days, B = 50, 3
+    edges = rs.integers(2**30, 2**32, size=(days, B), dtype=np.uint64)
+    edges = edges.astype(np.uint32)
+    hist = {"edges": edges}
+    obs = obs_lib.make_observables(("teps",))
+    ctx = obs_lib.ObsContext(num_people=1, num_scenarios=B)
+    res = obs_lib.observables_to_numpy(
+        obs_lib.observe_history(obs, hist, ctx))["teps"]
+    total = int(edges.astype(np.int64).sum())
+    assert total > 2**32
+    assert int(res["edges_total"]) == total
+    np.testing.assert_array_equal(res["daily"], edges)
+
+
+def test_compile_cache_location(monkeypatch):
+    """Entry points keep JAX's compile cache where JAX_COMPILATION_CACHE_DIR
+    says, setting nothing themselves; otherwise at the fixed
+    <checkout>/.jax_cache."""
+    import jax
+    from repro.launch import cli
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert cli.use_compile_cache() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = cli.use_compile_cache()
+        assert path == str(cli.CHECKOUT / ".jax_cache")
+        assert (cli.CHECKOUT / "src" / "repro").is_dir()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -1,4 +1,5 @@
 import numpy as np
+import pytest
 
 from repro.core import population as pop_lib
 from repro.data import (
@@ -111,3 +112,63 @@ def test_occupancy_packing_giant_alignment():
         (packed.person >= 0) & (packed.loc == 1)
     )
     assert giant_slots[0] % b == 0
+
+
+def _first_fit_loop(loc_sorted, b):
+    """The run-at-a-time first-fit-decreasing packing loop that
+    ``occupancy_pack_order`` replaces, kept verbatim as its reference."""
+    n = len(loc_sorted)
+    if n == 0:
+        return np.full((0,), -1, np.int64), 0
+    change = np.flatnonzero(np.diff(loc_sorted)) + 1
+    starts = np.concatenate([[0], change])
+    ends = np.concatenate([change, [n]])
+    counts = ends - starts
+    run_order = sorted(range(len(starts)), key=lambda i: (-counts[i], i))
+    segments, bins = [], []
+    for r in run_order:
+        c = int(counts[r])
+        if c >= b:
+            segments.append([r])
+            free = (-c) % b
+            if free:
+                bins.append([len(segments) - 1, free])
+        else:
+            for entry in bins:
+                if entry[1] >= c:
+                    segments[entry[0]].append(r)
+                    entry[1] -= c
+                    break
+            else:
+                segments.append([r])
+                bins.append([len(segments) - 1, b - c])
+    slot_src = []
+    for seg in segments:
+        seg_start = len(slot_src)
+        for r in seg:
+            slot_src.extend(range(int(starts[r]), int(ends[r])))
+        slot_src.extend([-1] * ((-(len(slot_src) - seg_start)) % b))
+    return np.asarray(slot_src, np.int64), len(slot_src)
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_occupancy_pack_order_matches_first_fit_loop(seed):
+    """Size-class-at-a-time packing gives the same slot order as the
+    run-at-a-time loop, on random run lengths: small runs only, big and
+    small mixed, and runs straddling the block size."""
+    rs = np.random.default_rng(seed)
+    for _ in range(40):
+        b = int(rs.choice([8, 32, 128]))
+        num_runs = int(rs.integers(0, 400))
+        lens = [
+            rs.geometric(0.15, num_runs),
+            np.where(rs.random(num_runs) < 0.1,
+                     rs.integers(b, 6 * b, num_runs),
+                     rs.integers(1, b, num_runs)),
+            rs.integers(1, 3 * b, num_runs),
+        ][seed % 3]
+        loc = np.repeat(np.arange(num_runs), lens)
+        ref, ref_extent = _first_fit_loop(loc, b)
+        got, extent = pop_lib.occupancy_pack_order(loc, b)
+        assert extent == ref_extent
+        np.testing.assert_array_equal(got, ref)

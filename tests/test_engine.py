@@ -20,7 +20,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import ScenarioBatch
-from repro.core import compat, disease
+from repro.core import disease
 from repro.core import interventions as iv
 from repro.core import simulator as sim_lib
 from repro.core import simulator_dist as sd
@@ -119,12 +119,13 @@ def _legacy_dist_hist(pop, batch, days, backend, workers=1):
 
         wspec = jax.tree.map(lambda _: P(None, sd.AXIS), week)
         rspec = jax.tree.map(lambda _: P(None, sd.AXIS), route)
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             worker, mesh=mesh,
             in_specs=(sd.dist_state_specs(), wspec, rspec,
                       sd.dist_param_specs()),
             out_specs=(sd.dist_state_specs(),
                        {k: P() for k in sd.STAT_KEYS}),
+            check_vma=False,
         ))
         state = sd.dist_init_state(s.disease, plan, len(iv_slots))
         final, hist = fn(state, week, route, params)

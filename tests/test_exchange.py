@@ -3,7 +3,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from repro.core import compat
 from repro.core import exchange as ex
 
 
@@ -40,11 +39,12 @@ def test_dispatch_combine_single_worker_roundtrip():
     send = jnp.asarray(plan.send_idx[0])
     recv = jnp.asarray(plan.recv_slot[0])
     vv, back = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             f, mesh=mesh,
             in_specs=(jax.sharding.PartitionSpec(), jax.sharding.PartitionSpec(),
                       jax.sharding.PartitionSpec()),
             out_specs=jax.sharding.PartitionSpec(),
+            check_vma=False,
         )
     )(send, recv, vals)
     # dispatch: each visit slot got its person's values

@@ -423,3 +423,46 @@ def test_traced_accumulator_extremes_bitwise_across_backends(kind):
         else:
             for a, r in zip(out, ref_out):
                 np.testing.assert_array_equal(np.asarray(a), np.asarray(r))
+
+
+@pytest.mark.parametrize("traced", [False, True])
+@pytest.mark.parametrize("backend", ["pallas", "pallas-compact"])
+def test_kernel_launches_split_on_row_runs(monkeypatch, backend, traced):
+    """A schedule longer than one launch takes (SMEM holds the schedule) is
+    cut into launches that end on row-run boundaries; the outputs are
+    bitwise those of a single pass."""
+    import jax
+    from repro.kernels.interactions import kernel
+
+    b = 64
+    day_v, p_loc, sus_pp, inf_pp, _ = make_case(3, Vn=900, L=40, P=300, b=b)
+    args, sched = backend_args(day_v, p_loc, sus_pp, inf_pp, b, 11, 2)
+    longest_run = int(np.bincount(sched.row_block).max())
+    assert longest_run < sched.num_pairs // 2  # several launches
+    src = None
+    if traced:
+        _, src = _with_sources(sus_pp, inf_pp, day_v,
+                               np.random.default_rng(5))
+    call = dict(block_size=b, backend=backend)
+    ref = (iops.interactions_auto_traced(*args, src_val=src, **call)
+           if traced else iops.interactions_auto_edges(*args, **call))
+    monkeypatch.setattr(kernel, "MAX_ROW_TILES", longest_run)
+    jax.clear_caches()
+    try:
+        out = (iops.interactions_auto_traced(*args, src_val=src, **call)
+               if traced else iops.interactions_auto_edges(*args, **call))
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    for a, r in zip(out, ref):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(r))
+
+
+def test_pallas_refuses_other_platforms(monkeypatch):
+    """Interpret mode is for CPU only: a platform that is neither TPU nor
+    CPU gets an error, never the interpreter."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="not on 'gpu'"):
+        iops.pallas_interpret()
