@@ -23,7 +23,7 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks import (
-        bench_kernels, bench_opts, bench_phases, bench_roofline,
+        bench_kernels, bench_opts, bench_roofline,
         bench_sharding, bench_strong, bench_sweep, bench_teps,
         bench_validation, bench_weak,
     )
@@ -36,7 +36,6 @@ def main() -> None:
         "fig6_strong": lambda: bench_strong.run(
             datasets=("twin-2k",) if fast else ("twin-2k", "md-mini", "ws-50k"),
             days=10 if fast else 30),
-        "fig7_phases": lambda: bench_phases.run(days=20 if fast else 60),
         "fig8_weak": lambda: bench_weak.run(days=7 if fast else 14),
         "fig9_validation": lambda: bench_validation.run(
             replicates=6 if fast else 30, days=60 if fast else 120),

@@ -19,9 +19,12 @@
 (c) Four chips. ``md``, 14 days, no intervention, two Monte Carlo
     replicates run once on one chip through ``repro.api.run``. Against it,
     bitwise: the ``workers`` layout (W=4, B=1, the first replicate) and
-    ``hybrid`` (2x2, both replicates). The sharded runs use the engine core
-    that ``repro.api.run`` builds for those layouts, so that the final
-    state can be checked to sit on all four devices.
+    ``hybrid`` (2x2, both replicates), in every history key but
+    ``live_tiles``. Each worker tiles its own shard, so that count follows
+    the layout; it is held to the layout's own schedule instead (see
+    :func:`own_live_tiles`). The sharded runs use the engine core that
+    ``repro.api.run`` builds for those layouts, so that the final state can
+    be checked to sit on all four devices.
 
 Earlier lines of the output report phase wall times, sizes and rates. The
 last line is one JSON object naming the device. Without a TPU, or when any
@@ -43,6 +46,8 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 
 import numpy as np  # noqa: E402
 
+from bench.clock import CompileClock  # noqa: E402
+
 
 def log(*parts):
     print(*parts, flush=True)
@@ -51,24 +56,6 @@ def log(*parts):
 def require(ok, what):
     if not ok:
         raise RuntimeError(f"check failed: {what}")
-
-
-class CompileClock:
-    """When, and for how long, XLA compiled: JAX's monitoring events for
-    backend compiles, each stamped with the time it ended. (Tracing and
-    lowering events nest, so summing them would count time twice; they
-    stay in the run time.)"""
-
-    def __init__(self, monitoring):
-        self.events = []
-        monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.events.append((time.perf_counter(), duration))
-
-    def seconds(self, since):
-        return sum(d for t, d in self.events if t >= since)
 
 
 def timed_run(api, spec, pop, clock):
@@ -121,6 +108,21 @@ def same_history(a, b, label):
                 f"(day, scenario) {tuple(diff[0])}: {a[k][tuple(diff[0])]} "
                 f"vs {b[k][tuple(diff[0])]}")
     log(f"{label}: all {len(a)} history keys bitwise equal")
+
+
+def own_live_tiles(core, hist, label):
+    """Hold a sharded layout's ``live_tiles`` to its own schedule: every
+    day with traversed edges has a live tile, and no day has more live
+    tiles than the layout schedules on its busiest day of the week."""
+    pa = np.asarray(core.week["pa"])
+    most = int(pa.reshape(pa.shape[0], -1).sum(axis=1).max())
+    live = hist["live_tiles"].astype(np.int64)
+    require(np.all(live[hist["edges"] > 0] > 0),
+            f"{label}: a day with edges has no live tile")
+    require(live.max() <= most,
+            f"{label}: {live.max()} live tiles of {most} scheduled")
+    log(f"{label}: live_tiles {live.min()}-{live.max()} a day, of at most "
+        f"{most} scheduled")
 
 
 def phase_a(api, clock):
@@ -235,8 +237,10 @@ def phase_c(api, clock):
         devices = final.health.sharding.device_set
         require(len(devices) == 4,
                 f"{label}: final state on {len(devices)} device(s)")
-        same_history({k: v[:, :replicates] for k, v in ref.history.items()},
-                     hist, f"[c] {label} vs one chip")
+        what = f"[c] {label} vs one chip"
+        same_history({k: v[:, :replicates] for k, v in ref.history.items()
+                      if k != "live_tiles"}, hist, what)
+        own_live_tiles(core, hist, what)
 
 
 def main(argv=None):

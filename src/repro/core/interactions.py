@@ -113,7 +113,8 @@ def day_exposure(
     #               for the EpiHiper-style static-network baseline)
     backend: str = "jnp",
 ):
-    """Returns (per-person propensity A (P,), total sus-inf contacts)."""
+    """Returns (per-person propensity A (P,), total sus-inf contacts, live
+    tiles)."""
     take = lambda a: jax.lax.dynamic_index_in_dim(a, dow, 0, keepdims=False)
     pid, loc = take(week.pid), take(week.loc)
     start, end = take(week.start), take(week.end)
@@ -132,7 +133,7 @@ def day_exposure(
     meta = jnp.stack(
         [jnp.asarray(seed, jnp.uint32), jnp.asarray(contact_day, jnp.uint32)]
     )
-    acc, cnt = iops.interactions_auto(
+    acc, cnt, _, n_live = iops.interactions_auto_edges(
         eff_pid, loc, start, end, p_v, sus_v, inf_v,
         row_idx, col_idx, row_start, pair_active, col_inf, row_sus, meta,
         block_size=week.block_size, backend=backend,
@@ -141,4 +142,4 @@ def day_exposure(
     A = jax.ops.segment_sum(
         jnp.where(active, acc, 0.0), safe_pid, num_segments=num_people
     ) * jnp.asarray(tau, jnp.float32)  # asarray: tau may be a traced scalar
-    return A, jnp.sum(cnt, dtype=jnp.uint32)
+    return A, jnp.sum(cnt, dtype=jnp.uint32), n_live.astype(jnp.uint32)

@@ -13,8 +13,7 @@ serving every layout (``EngineCore.single(...).run1(...)`` is the
 single-scenario front door; ``repro.api.run()`` the spec-driven one). The
 pure functions here (``day_step``, ``run_scan``, ``phase_*``) remain the
 *reference semantics* the engine core is pinned against bitwise
-(tests/test_engine.py), plus :func:`run_eager`, the per-phase-timed
-day-at-a-time driver benchmarks use.
+(tests/test_engine.py).
 
 The day step is factored into pure functions of ``(static, week,
 contact_prob, params, state)``:
@@ -41,8 +40,7 @@ Phases per day (matching the paper's phase breakdown, Fig 7):
 from __future__ import annotations
 
 import dataclasses
-import time
-from typing import Optional, Sequence
+from typing import Sequence
 
 import numpy as np
 import jax
@@ -61,18 +59,22 @@ from repro.core import transmission as tx_lib
 # "edges" is the traversed-edge count (the TEPS numerator): numerically
 # equal to "contacts", but measured *inside* the Pallas kernel on the
 # pallas-compact backend and derived host-side everywhere else — keeping
-# both makes the kernel counter a cross-checked quantity.
+# both makes the kernel counter a cross-checked quantity. "live_tiles"
+# counts the tiles with work today (the ``live_tiles`` predicate every
+# backend shares): the count the Pallas launchers compact to. Each worker
+# tiles its own shard, so it follows the number of workers.
 STAT_KEYS = ("day", "new_infections", "cumulative", "infectious",
-             "susceptible", "contacts", "edges",
+             "susceptible", "contacts", "edges", "live_tiles",
              # Per-agent intervention telemetry (PR 7): constant zero when
              # no TestTraceIsolate slot exists (the reference path below
              # emits the zeros; the unified engine computes them).
              "tests_used", "isolated", "traced")
 
-#: dtype of the daily "contacts"/"edges" counts. They are non-negative, so
-#: unsigned 32 bits give twice int32's range with or without x64: md's
-#: daily count is below 10^9 (visits x at most ~40 contacts per visit) and
-#: wraps at 2^32. Totals over days are summed exactly by TEPS.
+#: dtype of the daily "contacts"/"edges"/"live_tiles" counts. They are
+#: non-negative, so unsigned 32 bits give twice int32's range with or
+#: without x64: md's daily count is below 10^9 (visits x at most ~40
+#: contacts per visit) and wraps at 2^32. Totals over days are summed
+#: exactly by TEPS.
 COUNT_DTYPE = jnp.uint32
 
 
@@ -228,7 +230,7 @@ def phase_interact(
     )
 
 
-def phase_update(static, params, state, A, contacts, vaccinated):
+def phase_update(static, params, state, A, contacts, live, vaccinated):
     """Phase 3: infection sampling, seeding, FSA update, triggers."""
     infected = tx_lib.sample_infections(A, params.seed, state.day)
 
@@ -274,6 +276,7 @@ def phase_update(static, params, state, A, contacts, vaccinated):
         # Host-side traversed edges; the unified engine substitutes the
         # in-kernel counter on the pallas-compact backend.
         "edges": contacts.astype(COUNT_DTYPE),
+        "live_tiles": live,
         # The reference path carries no per-agent interventions; the stats
         # are constant zeros (the engine must match them bitwise whenever
         # no TestTraceIsolate slot is configured).
@@ -303,11 +306,11 @@ def day_step(static, week, contact_prob, params: SimParams, state: SimState):
     visit_ok, loc_open, person_sus, person_inf, vaccinated = phase_visits(
         static, params, state
     )
-    A, contacts = phase_interact(
+    A, contacts, live = phase_interact(
         static, week, contact_prob, params, state,
         visit_ok, loc_open, person_sus, person_inf,
     )
-    return phase_update(static, params, state, A, contacts, vaccinated)
+    return phase_update(static, params, state, A, contacts, live, vaccinated)
 
 
 def run_scan(static, week, contact_prob, params, state, days: int):
@@ -342,8 +345,8 @@ def legacy_parts(core):
 
     This is the bridge between the unified engine (which owns population
     compilation) and the reference semantics in this module: parity tests
-    and :func:`run_eager` drive ``day_step``/``phase_*`` with exactly the
-    arrays the engine scans over."""
+    drive ``day_step``/``phase_*`` with exactly the arrays the engine scans
+    over."""
     from repro.engine.core import index_params  # cycle-free at call time
 
     assert core.layout == "local" and core.num_real == 1, \
@@ -356,45 +359,6 @@ def legacy_parts(core):
         backend=core.backend,
     )
     return static, core.week_data, jnp.asarray(core.pop.contact_prob), params
-
-
-def run_eager(core, days: int, state: Optional[SimState] = None):
-    """Day-at-a-time loop with per-phase wall times (benchmarks Fig 4/7).
-
-    ``core`` is a B=1 ``layout="local"`` EngineCore. Phases are timed by
-    running each phase's jitted sub-program to completion; numbers include
-    dispatch overhead, which is the honest CPU-side analog of the paper's
-    per-phase projections. Trajectories are bitwise-identical to
-    ``core.run1`` (same per-day arithmetic, scan vs Python loop)."""
-    static, week, contact_prob, params = legacy_parts(core)
-    state = state if state is not None else core.init_state1()
-    p1 = jax.jit(lambda st: phase_visits(static, params, st))
-    p2 = jax.jit(
-        lambda st, ok, op, ps, pi: phase_interact(
-            static, week, contact_prob, params, st, ok, op, ps, pi,
-        )
-    )
-    p3 = jax.jit(
-        lambda st, A, c, v: phase_update(static, params, st, A, c, v)
-    )
-    hist: dict[str, list] = {}
-    times = {"visits": [], "interact": [], "update": []}
-    for _ in range(days):
-        t0 = time.perf_counter()
-        visit_ok, loc_open, ps, pi, vacc = jax.block_until_ready(p1(state))
-        t1 = time.perf_counter()
-        A, contacts = jax.block_until_ready(p2(state, visit_ok, loc_open, ps, pi))
-        t2 = time.perf_counter()
-        state, stats = jax.block_until_ready(p3(state, A, contacts, vacc))
-        t3 = time.perf_counter()
-        times["visits"].append(t1 - t0)
-        times["interact"].append(t2 - t1)
-        times["update"].append(t3 - t2)
-        for k, v in jax.device_get(stats).items():
-            hist.setdefault(k, []).append(v)
-    return state, {k: np.asarray(v) for k, v in hist.items()}, {
-        k: np.asarray(v) for k, v in times.items()
-    }
 
 
 def attack_rate(hist) -> float:

@@ -448,7 +448,7 @@ def dist_day_step(
     meta = jnp.stack(
         [params.seed.astype(jnp.uint32), contact_day.astype(jnp.uint32)]
     )
-    acc, cnt = iops.interactions_auto(
+    acc, cnt, _, n_live = iops.interactions_auto_edges(
         eff_pid, loc, vstart, vend, p_v, sus_v, inf_v,
         row_i, col_i, row_s, pair_a, col_inf, row_sus, meta,
         block_size=static.block_size, backend=static.backend,
@@ -521,6 +521,7 @@ def dist_day_step(
         # Host-side traversed edges (== contacts by construction); see
         # simulator.STAT_KEYS for why it is a separate key.
         "edges": contacts,
+        "live_tiles": jax.lax.psum(n_live.astype(sim_lib.COUNT_DTYPE), axis),
         # Legacy reference path: no per-agent interventions (zeros, like
         # simulator.phase_update — the unified engine computes these).
         "tests_used": jnp.zeros((), jnp.int32),

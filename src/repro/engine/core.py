@@ -53,8 +53,9 @@ LAYOUTS = ("local", "workers", "scenarios", "hybrid")
 #: rather than silently spliced into a trajectory. v2: history gained the
 #: "edges" stat (in-kernel traversed-edge telemetry). v3: per-agent
 #: interventions — SimState gained tested/traced/isolated_until and
-#: history gained the "tests_used"/"isolated"/"traced" stats.
-CORE_VERSION = "engine-v3"
+#: history gained the "tests_used"/"isolated"/"traced" stats. v4: history
+#: gained the "live_tiles" stat (tiles with work each day).
+CORE_VERSION = "engine-v4"
 
 _STATE_FIELDS = tuple(f.name for f in dataclasses.fields(sim_lib.SimState))
 

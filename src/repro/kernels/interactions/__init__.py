@@ -3,6 +3,5 @@ from repro.kernels.interactions.ops import (  # noqa: F401
     interactions_blocked_jnp,
     interactions_blocked_scan,
     interactions_compact,
-    interactions_pallas,
 )
 from repro.kernels.interactions.ref import interactions_dense  # noqa: F401

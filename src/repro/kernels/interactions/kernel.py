@@ -53,7 +53,9 @@ Launches: the schedule arrays ride in SMEM (1 MiB on v5e), which holds
 about 80k tiles, and a full-scale twin schedules more. So a schedule is cut
 into launches of at most ``MAX_ROW_TILES`` tiles, each ending on a row-run
 boundary: every row block is accumulated within one launch, in schedule
-order. The per-visit outputs are aliased from launch to launch, so rows a
+order. Every launch is a ``pallas_call`` named
+``interactions_kernel``: the name the compiled program and its traces
+carry. The per-visit outputs are aliased from launch to launch, so rows a
 launch does not visit keep their values (zeros, if no launch visits them).
 
 Layout: every visit array enters as a lane-major (1, V) view and moves in
@@ -205,6 +207,7 @@ def _launch_one(rows, cols, live, n, meta, visits, src, outs, *,
         out_shape=out_shape,
         input_output_aliases={first_out + i: i for i in range(len(outs))},
         interpret=interpret,
+        name="interactions_kernel",
     )(*operands)
     return tuple(outs), edges
 
@@ -273,9 +276,10 @@ def interactions_pallas_call(
 ):
     """The kernel over the padded schedule. All visit arrays are (V,) with
     V % block_size == 0; schedule arrays are (NP,) / (NB,). Returns
-    (acc (V,), cnt (V,), edges () i32); with ``src_val`` (tracing-source
-    weights), (acc, cnt, trc, edges). Row blocks the schedule never visits
-    read zero."""
+    (acc (V,), cnt (V,), edges () i32, n_live () i32); with ``src_val``
+    (tracing-source weights), (acc, cnt, trc, edges, n_live). ``n_live`` is
+    the number of tiles with work today. Row blocks the schedule never
+    visits read zero."""
     del row_start  # the kernel finds row runs from the row indices
     assert pid.shape[0] % block_size == 0
     live = live_tiles(row_idx, col_idx, pair_active, col_has_inf, row_has_sus)
@@ -285,7 +289,7 @@ def interactions_pallas_call(
         _visits(pid, loc, start, end, p_loc, sus_val, inf_val),
         None if src_val is None else src_val.astype(jnp.float32),
         block_size=block_size, interpret=interpret,
-    )
+    ) + (live.sum().astype(jnp.int32),)
 
 
 @functools.partial(jax.jit, static_argnames=("block_size", "interpret"))
@@ -307,10 +311,11 @@ def interactions_pallas_compact_call(
     live = live_tiles(row_idx, col_idx, pair_active, col_has_inf, row_has_sus)
     # Stable partition: live tiles first, original (row-major) order kept.
     order = jnp.argsort(jnp.where(live, 0, 1), stable=True)
+    n_live = live.sum().astype(jnp.int32)
     return _launch(
-        row_idx[order], col_idx[order], live[order],
-        live.sum().astype(jnp.int32), meta.astype(jnp.uint32),
+        row_idx[order], col_idx[order], live[order], n_live,
+        meta.astype(jnp.uint32),
         _visits(pid, loc, start, end, p_loc, sus_val, inf_val),
         None if src_val is None else src_val.astype(jnp.float32),
         block_size=block_size, interpret=interpret,
-    )
+    ) + (n_live,)

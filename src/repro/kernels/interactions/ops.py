@@ -1,9 +1,8 @@
 """Jitted wrappers for the interaction pass.
 
-Six interchangeable implementations, all bitwise-identical in output
-(tested against each other and the dense oracle):
+Five interchangeable backends, all bitwise-identical in output (tested
+against each other and the O(V^2) oracle ``ref.interactions_dense``):
 
-  interactions_dense          O(V^2) oracle (ref.py) — tests only.
   interactions_blocked_jnp    vmap over the block-pair schedule; vectorized,
                               no runtime skip — the throughput CPU path at
                               high prevalence.
@@ -15,10 +14,10 @@ Six interchangeable implementations, all bitwise-identical in output
                               stable sort) and runs a fori_loop bounded by
                               the *traced* live count, so a day with 0.1%
                               live tiles costs ~0.1% of the tile work.
-  interactions_pallas         the TPU kernel (kernel.py) over the padded
+  pallas                      the TPU kernel (kernel.py) over the padded
                               schedule; compiled on TPU, interpret mode on
                               CPU, refused elsewhere.
-  interactions_pallas_compact the fused kernel: the compact backend's
+  pallas-compact              the fused kernel: the compact backend's
                               schedule compaction feeding the Pallas kernel
                               directly via scalar prefetch, with an
                               in-kernel traversed-edge counter (the
@@ -29,7 +28,9 @@ Six interchangeable implementations, all bitwise-identical in output
 All take the same (V,)-shaped visit arrays (location-sorted, padded with
 pid == -1) plus the static BlockSchedule arrays and the two per-block
 short-circuit flags (col_has_inf / row_has_sus), and return per-visit
-propensity sums (before the global tau factor) and contact counts.
+propensity sums (before the global tau factor) and contact counts, then
+the live-tile count each already holds: the number of tiles the shared
+``live_tiles`` predicate marks as having work today (i32 scalar).
 
 Bitwise equality across backends is structural, not accidental: every
 backend accumulates live tiles in the same row-major schedule order, and
@@ -117,7 +118,7 @@ def interactions_blocked_jnp(
         jax.ops.segment_sum(o, row_idx, num_segments=nb).reshape(V)
         for o in outs
     )
-    return folded
+    return folded + (live.sum().astype(jnp.int32),)
 
 
 @functools.partial(jax.jit, static_argnames=("block_size",))
@@ -163,7 +164,7 @@ def interactions_blocked_scan(
         acc0, cnt0, jnp.zeros((V,), jnp.int32)
     )
     out, _ = jax.lax.scan(step, carry0, (row_idx, col_idx, live))
-    return out
+    return out + (live.sum().astype(jnp.int32),)
 
 
 @functools.partial(jax.jit, static_argnames=("block_size",))
@@ -195,7 +196,7 @@ def interactions_compact(
     order = jnp.argsort(jnp.where(live, 0, 1), stable=True)
     rows_c = row_idx[order]
     cols_c = col_idx[order]
-    n_live = live.sum()
+    n_live = live.sum().astype(jnp.int32)
 
     def _upd(arr, rb, delta):
         return jax.lax.dynamic_update_slice_in_dim(
@@ -215,7 +216,7 @@ def interactions_compact(
     carry0 = (acc0, cnt0) if src_val is None else (
         acc0, cnt0, jnp.zeros((V,), jnp.int32)
     )
-    return jax.lax.fori_loop(0, n_live, body, carry0)
+    return jax.lax.fori_loop(0, n_live, body, carry0) + (n_live,)
 
 
 def pallas_interpret() -> bool:
@@ -235,42 +236,34 @@ _PALLAS_CALLS = {
     "pallas-compact": interactions_pallas_compact_call,
 }
 
-
-def _pallas_full(backend, *args, block_size: int, src_val=None):
-    """The kernel's outputs: (acc, cnt, edges), or (acc, cnt, trc, edges)
-    with ``src_val``. ``edges`` is the in-kernel traversed-edge count, the
-    sum of contact counts over live tiles, i.e. exactly ``cnt.sum()``."""
-    return _PALLAS_CALLS[backend](
-        *args, block_size=block_size, interpret=pallas_interpret(),
-        src_val=src_val,
-    )
-
-
-def interactions_pallas(*args, **kwargs):
-    """The kernel over the padded schedule (kernel.py): one grid step per
-    scheduled tile, dead tiles skipped in the kernel."""
-    return tuple(_pallas_full("pallas", *args, **kwargs)[:-1])
-
-
-def interactions_pallas_compact(*args, **kwargs):
-    """The fused active-set kernel: the compact backend's schedule
-    compaction (the *same* stable sort, so accumulation order — and every
-    f32 bit — matches `compact` and `jnp`) feeding the kernel, whose grid
-    steps past the live prefix issue no DMA and no flops."""
-    return tuple(_pallas_full("pallas-compact", *args, **kwargs)[:-1])
-
-
-BACKENDS = {
+_JNP_CALLS = {
     "jnp": interactions_blocked_jnp,
     "scan": interactions_blocked_scan,
     "compact": interactions_compact,
-    "pallas": interactions_pallas,
-    "pallas-compact": interactions_pallas_compact,
 }
 
 
+def _run(backend, *args, block_size: int, src_val=None):
+    """Any backend's whole output: (acc, cnt, edges, n_live), or (acc, cnt,
+    trc, edges, n_live) with ``src_val``. ``edges`` is the traversed-edge
+    count, the sum of contact counts over live tiles, i.e. exactly
+    ``cnt.sum()``: the Pallas kernels accumulate it in the kernel, every
+    other backend sums ``cnt``. ``n_live`` is the live-tile count the
+    backend holds (the Pallas launchers compact to it)."""
+    if backend in _PALLAS_CALLS:
+        return _PALLAS_CALLS[backend](
+            *args, block_size=block_size, interpret=pallas_interpret(),
+            src_val=src_val,
+        )
+    *outs, n_live = _JNP_CALLS[backend](
+        *args, block_size=block_size, src_val=src_val
+    )
+    return (*outs, outs[1].sum().astype(jnp.int32), n_live)
+
+
 def interactions_auto(*args, backend: str = "jnp", **kwargs):
-    """Dispatch by backend name.
+    """Dispatch by backend name: ``(acc, cnt)``, or ``(acc, cnt, trc)``
+    with ``src_val``.
 
     'jnp' is the dense-throughput CPU default, 'compact' the active-set
     engine (work ∝ live epidemic activity), 'pallas' the TPU kernel over
@@ -278,41 +271,38 @@ def interactions_auto(*args, backend: str = "jnp", **kwargs):
     (compaction + tile math + edge telemetry). The Pallas kernels compile
     on TPU and run in interpret mode on CPU (:func:`pallas_interpret`).
     """
-    return BACKENDS[backend](*args, **kwargs)
+    return _run(backend, *args, **kwargs)[:-2]
 
 
 def interactions_auto_edges(*args, backend: str = "jnp", **kwargs):
     """Like ``interactions_auto`` but also returns the traversed-edge count
-    (i32 scalar) — the TEPS numerator.
+    (i32 scalar, the TEPS numerator) and the live-tile count (i32 scalar):
+    ``(acc, cnt, edges, n_live)``.
 
-    For the Pallas backends the count comes from the in-kernel
+    For the Pallas backends the edge count comes from the in-kernel
     accumulator; every other backend derives it as ``cnt.sum()``. Both are
     sums of the same live-tile contact counts, so the two routes agree
-    exactly (asserted in tests/test_interactions.py).
+    exactly (asserted in tests/test_interactions.py). ``n_live`` comes
+    from the one ``live_tiles`` predicate, so it is equal on every backend.
     """
-    if backend in _PALLAS_CALLS:
-        return _pallas_full(backend, *args, **kwargs)
-    acc, cnt = BACKENDS[backend](*args, **kwargs)
-    return acc, cnt, cnt.sum().astype(jnp.int32)
+    return _run(backend, *args, **kwargs)
 
 
 def interactions_auto_traced(*args, backend: str = "jnp", src_val=None,
                              **kwargs):
     """Traced twin of ``interactions_auto_edges``: runs the interaction
     pass with the second (contact-tracing) accumulator enabled and returns
-    ``(acc, cnt, edges, trc)``.
+    ``(acc, cnt, edges, trc, n_live)``.
 
     ``src_val`` is the per-visit tracing-source weight (>0 where the
     visitor tested positive today); ``trc`` is the per-visit count of
     traced contacts, accumulated tile-for-tile alongside ``acc``/``cnt``
     so it is bitwise identical across all five backends. ``edges`` keeps
-    its meaning (and, on the Pallas backends, its in-kernel route).
+    its meaning (and, on the Pallas backends, its in-kernel route), and so
+    does ``n_live``.
     """
     assert src_val is not None
-    if backend in _PALLAS_CALLS:
-        acc, cnt, trc, edges = _pallas_full(
-            backend, *args, src_val=src_val, **kwargs
-        )
-        return acc, cnt, edges, trc
-    acc, cnt, trc = BACKENDS[backend](*args, src_val=src_val, **kwargs)
-    return acc, cnt, cnt.sum().astype(jnp.int32), trc
+    acc, cnt, trc, edges, n_live = _run(
+        backend, *args, src_val=src_val, **kwargs
+    )
+    return acc, cnt, edges, trc, n_live

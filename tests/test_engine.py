@@ -203,11 +203,18 @@ def test_layout_matrix_bitwise_vs_prerefactor(pop, batch, backend):
     ("workers", dict(workers=4)),
 ])
 def test_layout_matrix_multidevice(pop, batch, layout, kw):
-    """The same matrix on real >1-device meshes (CI multidevice job)."""
+    """The same matrix on real >1-device meshes (CI multidevice job). Each
+    worker tiles its own shard, so ``live_tiles`` is held to the legacy
+    step at the same worker count; every other key to one device."""
     if len(jax.devices()) < 4:
         pytest.skip("needs >= 4 devices "
                     "(XLA_FLAGS=--xla_force_host_platform_device_count=4)")
     _, hists_ref = _legacy_single_hist(pop, batch, DAYS, "jnp")
+    workers = kw.get("workers", 1)
+    if workers > 1:
+        _, tiles_ref = _legacy_dist_hist(pop, batch, DAYS, "jnp", workers)
+        for ref, tiles in zip(hists_ref, tiles_ref):
+            ref["live_tiles"] = tiles["live_tiles"]
     core = EngineCore(pop, batch, layout=layout, backend="jnp", **kw)
     if layout == "workers":
         for i in range(len(batch)):
@@ -391,6 +398,7 @@ def test_tti_bitwise_across_backends(pop, tti_kw):
     assert ref["tests_used"].sum() > 0
     assert ref["traced"].sum() > 0
     assert ref["isolated"].sum() > 0
+    assert ref["live_tiles"].sum() > 0
     for backend in ("scan", "compact", "pallas", "pallas-compact"):
         h = _tti_hist(pop, tti_kw, backend=backend)
         for k in sim_lib.STAT_KEYS:
@@ -417,12 +425,17 @@ def test_tti_bitwise_across_layouts(pop, tti_kw):
 def test_tti_multidevice(pop, tti_kw, layout, kw):
     """Tracing + test budget on real >1-device meshes: the traced-contact
     halo rides the exposure exchange and the budget's order statistic
-    gathers per-worker candidates — both must stay bitwise."""
+    gathers per-worker candidates — both must stay bitwise. Each worker
+    tiles its own shard, so ``live_tiles`` is held to another backend on
+    the same mesh; every other key to one device."""
     if len(jax.devices()) < 4:
         pytest.skip("needs >= 4 devices "
                     "(XLA_FLAGS=--xla_force_host_platform_device_count=4)")
     ref = _tti_hist(pop, tti_kw)
     h = _tti_hist(pop, tti_kw, layout=layout, **kw)
+    if kw.get("workers", 1) > 1:
+        ref["live_tiles"] = _tti_hist(pop, tti_kw, layout=layout,
+                                      backend="compact", **kw)["live_tiles"]
     for k in sim_lib.STAT_KEYS:
         np.testing.assert_array_equal(ref[k], h[k], err_msg=f"{layout}/{k}")
 
@@ -455,13 +468,15 @@ def test_mixed_family_slot_structure_validated(pop):
 # ---------------------------------------------------------------------------
 
 # (workers axis) exposure all_to_all out+back, halo gather, psum reductions
-WORKERS_COLLECTIVES = {"all_to_all": 2, "all_gather": 1, "psum": 5}
-# (scenarios axis) replicated-stat gathers only — no cross-scenario math
-SCENARIOS_COLLECTIVES = {"all_gather": 10}
+# (one per worker-summed stat, live_tiles among them)
+WORKERS_COLLECTIVES = {"all_to_all": 2, "all_gather": 1, "psum": 6}
+# (scenarios axis) replicated-stat gathers only (one per stat) — no
+# cross-scenario math
+SCENARIOS_COLLECTIVES = {"all_gather": 11}
 # (workers x scenarios) exactly the sum of the two axes' schedules, plus
 # one extra all_gather where the scenario axis collects the worker-reduced
 # stats
-HYBRID_COLLECTIVES = {"all_to_all": 2, "all_gather": 11, "psum": 5}
+HYBRID_COLLECTIVES = {"all_to_all": 2, "all_gather": 12, "psum": 6}
 
 
 @pytest.mark.parametrize("layout,kw,expected", [
@@ -518,3 +533,46 @@ def test_local_rank_threshold_budget_semantics():
     # budget larger than the eligible pool: threshold lands on the 4.0 tier
     T, G = topo.rank_threshold(score, gpid, jnp.asarray(4, jnp.int32), 5, 1)
     assert float(T) == 4.0
+
+
+# ---------------------------------------------------------------------------
+# the day loop's named scopes are metadata: the optimised program with them
+# is the program without them, once op_name and stack frames are stripped.
+# ---------------------------------------------------------------------------
+
+
+def _stripped_hlo(pop, batch, observables):
+    import re
+
+    from repro.api import observables as obs_lib
+
+    core = EngineCore(pop, batch, layout="local", backend="jnp")
+    ctx = obs_lib.ObsContext(num_people=pop.num_people,
+                             num_scenarios=len(batch))
+    text = core.runner_fn(2, observables).lower(
+        core.params, core.init_state(),
+        obs_lib.init_carries(observables, ctx), core.week, core.route,
+    ).compile().as_text()
+    return text, re.sub(r", metadata=\{[^}]*\}", "",
+                        text.split("\nFileNames\n")[0])
+
+
+def test_named_scopes_change_metadata_only(batch, monkeypatch):
+    import contextlib
+
+    from repro.api import observables as obs_lib
+    from repro.configs import get_epidemic
+    from repro.engine import day as day_lib
+
+    pop = get_epidemic("md-mini").build()
+    obs = obs_lib.make_observables(
+        ["daily_new_infections", "attack_rate", "peak_day",
+         "ensemble_mean_ci"])
+    scoped, with_scopes = _stripped_hlo(pop, batch, obs)
+    for name in day_lib.SCOPES:
+        assert f"({name})/" in scoped or f"/{name}/" in scoped, name
+    monkeypatch.setattr(day_lib, "_scope",
+                        lambda name: contextlib.nullcontext())
+    plain, without = _stripped_hlo(pop, batch, obs)
+    assert not any(f"({name})/" in plain for name in day_lib.SCOPES)
+    assert with_scopes == without

@@ -278,7 +278,20 @@ def test_short_circuit_zero_infectious():
 # ---------------------------------------------------------------------------
 # In-kernel traversed-edge telemetry: the pallas-compact SMEM accumulator
 # must equal the host-side count (sum of per-visit contact counts) exactly.
+# The live-tile count rides beside it: equal on every backend, and equal to
+# a numpy recount of the block flags.
 # ---------------------------------------------------------------------------
+
+
+def _numpy_live_tiles(args, b):
+    """Tiles with work: scheduled, an infectious visit in the column block
+    and a susceptible one in the row block, recounted from the visits."""
+    pid, sus_v, inf_v = (np.asarray(args[i]) for i in (0, 5, 6))
+    row, col, pair_active = (np.asarray(args[i]) for i in (7, 8, 10))
+    real = (pid >= 0).reshape(-1, b)
+    has_sus = ((sus_v.reshape(-1, b) > 0) & real).any(axis=1)
+    has_inf = ((inf_v.reshape(-1, b) > 0) & real).any(axis=1)
+    return int(((pair_active == 1) & has_inf[col] & has_sus[row]).sum())
 
 
 @pytest.mark.parametrize("kind", [
@@ -295,16 +308,21 @@ def test_in_kernel_edge_counter_matches_host(kind, packed):
     else:
         layout, extent = day_v, day_v.num_real
     args, _ = layout_args(layout, extent, p_loc, sus_pp, inf_pp, b, 21, 4)
+    want_live = _numpy_live_tiles(args, b)
     for backend in ALL_BACKENDS:
-        acc, cnt, edges = iops.interactions_auto_edges(
+        acc, cnt, edges, live = iops.interactions_auto_edges(
             *args, block_size=b, backend=backend
         )
         assert int(np.asarray(edges)) == int(np.asarray(cnt).sum()), backend
+        assert int(np.asarray(live)) == want_live, backend
     if kind == "all_infectious":
-        _, cnt, edges = iops.interactions_auto_edges(
+        _, cnt, edges, live = iops.interactions_auto_edges(
             *args, block_size=b, backend="pallas-compact"
         )
         assert int(np.asarray(edges)) > 0
+        assert int(np.asarray(live)) > 0
+    if kind == "zero_infectious":
+        assert want_live == 0
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
@@ -312,14 +330,16 @@ def test_in_kernel_edge_counter_random_schedules(seed):
     b = 64
     day_v, p_loc, sus_pp, inf_pp, _ = make_case(seed, b=b)
     args, _ = backend_args(day_v, p_loc, sus_pp, inf_pp, b, 123, 5)
-    _, cnt_ref, edges_ref = iops.interactions_auto_edges(
+    _, cnt_ref, edges_ref, live_ref = iops.interactions_auto_edges(
         *args, block_size=b, backend="jnp"
     )
-    _, cnt, edges = iops.interactions_auto_edges(
+    _, cnt, edges, live = iops.interactions_auto_edges(
         *args, block_size=b, backend="pallas-compact"
     )
     np.testing.assert_array_equal(np.asarray(cnt), np.asarray(cnt_ref))
     assert int(np.asarray(edges)) == int(np.asarray(edges_ref))
+    assert int(np.asarray(live)) == int(np.asarray(live_ref)) == \
+        _numpy_live_tiles(args, b) > 0
     assert int(np.asarray(edges)) == int(np.asarray(cnt_ref).sum())
 
 
@@ -353,9 +373,10 @@ def test_traced_accumulator_matches_dense_oracle(seed, backend):
     acc_d, cnt_d, trc_d = iref.interactions_dense_traced(
         *args[:7], src_v, 123, 5
     )
-    acc, cnt, edges, trc = iops.interactions_auto_traced(
+    acc, cnt, edges, trc, live = iops.interactions_auto_traced(
         *args, block_size=b, backend=backend, src_val=src_v
     )
+    assert int(np.asarray(live)) == _numpy_live_tiles(args, b)
     np.testing.assert_array_equal(np.asarray(trc), np.asarray(trc_d))
     np.testing.assert_array_equal(np.asarray(cnt), np.asarray(cnt_d))
     np.testing.assert_allclose(np.asarray(acc), np.asarray(acc_d), rtol=1e-6)
@@ -375,15 +396,16 @@ def test_traced_call_leaves_exposure_bitwise_unchanged(seed):
     _, src_v = _with_sources(sus_pp, inf_pp, day_v, rs)
     args, _ = backend_args(day_v, p_loc, sus_pp, inf_pp, b, 9, 2)
     for backend in ALL_BACKENDS:
-        acc0, cnt0, edges0 = iops.interactions_auto_edges(
+        acc0, cnt0, edges0, live0 = iops.interactions_auto_edges(
             *args, block_size=b, backend=backend
         )
-        acc, cnt, edges, _ = iops.interactions_auto_traced(
+        acc, cnt, edges, _, live = iops.interactions_auto_traced(
             *args, block_size=b, backend=backend, src_val=src_v
         )
         np.testing.assert_array_equal(np.asarray(acc), np.asarray(acc0))
         np.testing.assert_array_equal(np.asarray(cnt), np.asarray(cnt0))
         assert int(np.asarray(edges)) == int(np.asarray(edges0))
+        assert int(np.asarray(live)) == int(np.asarray(live0))
 
 
 def test_traced_accumulator_zero_sources():
@@ -392,7 +414,7 @@ def test_traced_accumulator_zero_sources():
     args, _ = backend_args(day_v, p_loc, sus_pp, inf_pp, b, 3, 1)
     src_v = jnp.zeros((args[0].shape[0],), jnp.float32)
     for backend in ALL_BACKENDS:
-        _, _, _, trc = iops.interactions_auto_traced(
+        _, _, _, trc, _ = iops.interactions_auto_traced(
             *args, block_size=b, backend=backend, src_val=src_v
         )
         assert int(np.abs(np.asarray(trc)).sum()) == 0, backend

@@ -285,10 +285,15 @@ def reference(pop):
     return api.run(_spec(), population=pop)
 
 
-def _assert_bitwise(ref, res):
+def _assert_bitwise(ref, res, layout_from=None):
+    """``layout_from``: the day the run changed its worker count. The
+    ``live_tiles`` counter counts each worker's own tiles, so it is
+    compared up to that day only; every other key on every day."""
     assert set(ref.history) == set(res.history)
     for k in ref.history:
-        np.testing.assert_array_equal(ref.history[k], res.history[k],
+        days = layout_from if k == "live_tiles" else None
+        np.testing.assert_array_equal(ref.history[k][:days],
+                                      res.history[k][:days],
                                       err_msg=f"history[{k}] diverged")
     for k in ref.observables:
         r, s = ref.observables[k], res.observables[k]
@@ -409,7 +414,7 @@ def test_device_loss_elastic_shrink(pop, tmp_path, engine, replicates):
         population=pop,
         chaos=ChaosSchedule((ChaosEvent("device_loss", day=6,
                                         workers_lost=1),)))
-    _assert_bitwise(ref, res)
+    _assert_bitwise(ref, res, layout_from=6)
     rep = res.provenance["resilience"]
     assert rep["device_losses"] == [{"workers_before": 2, "workers_after": 1}]
     assert rep["final_workers"] == 1

@@ -109,14 +109,6 @@ def test_static_network_weekly_repeat(pop):
     np.testing.assert_array_equal(c[:7], c[7:14])
 
 
-def test_run_eager_matches_scan(pop):
-    sim = make_sim(pop, seed=5)
-    _, h1 = sim.run1(10)
-    _, h2, times = simulator.run_eager(sim, 10)
-    np.testing.assert_array_equal(h1["cumulative"], h2["cumulative"])
-    assert set(times) == {"visits", "interact", "update"}
-
-
 def test_checkpoint_restore_exact(pop):
     sim = make_sim(pop, seed=5)
     s_mid, h1 = sim.run1(10)
