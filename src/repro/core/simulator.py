@@ -200,8 +200,10 @@ def phase_visits(static: SimStatic, params: SimParams, state: SimState):
         static.num_people,
         static.num_locations,
     )
-    person_sus = params.sus_table[state.health] * params.beta_sus * sus_mult
-    person_inf = params.inf_table[state.health] * params.beta_inf * inf_mult
+    person_sus = (disease_lib.lookup(params.sus_table, state.health)
+                  * params.beta_sus * sus_mult)
+    person_inf = (disease_lib.lookup(params.inf_table, state.health)
+                  * params.beta_inf * inf_mult)
     return visit_ok, loc_open, person_sus, person_inf, vaccinated
 
 
@@ -238,7 +240,7 @@ def phase_update(static, params, state, A, contacts, live, vaccinated):
         h, d = h_d
         pid = jnp.arange(static.num_people, dtype=jnp.uint32)
         u = rng.uniform(params.seed, rng.SEED_CHOICE, state.day, pid)
-        sus = params.sus_table[h] > 0.0
+        sus = disease_lib.lookup(params.sus_table, h) > 0.0
         u = jnp.where(sus, u, 2.0)
         k = jnp.minimum(params.seed_per_day, static.num_people) - 1
         thresh = jnp.sort(u)[jnp.maximum(k, 0)]
@@ -250,7 +252,7 @@ def phase_update(static, params, state, A, contacts, live, vaccinated):
         lambda _: jnp.zeros((static.num_people,), bool),
         (state.health, state.dwell),
     )
-    can_infect = params.sus_table[state.health] > 0.0
+    can_infect = disease_lib.lookup(params.sus_table, state.health) > 0.0
     new_mask = (infected | seeded) & can_infect
     health, dwell = disease_lib.update_health_tables(
         params.cum_trans,
@@ -265,13 +267,20 @@ def phase_update(static, params, state, A, contacts, live, vaccinated):
     )
     new_count = new_mask.sum().astype(jnp.int32)
     cumulative = state.cumulative + new_count
-    infectious = (params.inf_table[health] > 0.0).sum().astype(jnp.int32)
+    infectious = (
+        (disease_lib.lookup(params.inf_table, health) > 0.0)
+        .sum().astype(jnp.int32)
+    )
+    susceptible = (
+        (disease_lib.lookup(params.sus_table, health) > 0.0)
+        .sum().astype(jnp.int32)
+    )
     stats = {
         "day": state.day,
         "new_infections": new_count,
         "cumulative": cumulative,
         "infectious": infectious,
-        "susceptible": (params.sus_table[health] > 0.0).sum().astype(jnp.int32),
+        "susceptible": susceptible,
         "contacts": contacts.astype(COUNT_DTYPE),
         # Host-side traversed edges; the unified engine substitutes the
         # in-kernel counter on the pallas-compact backend.

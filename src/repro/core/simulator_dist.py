@@ -420,8 +420,10 @@ def dist_day_step(
         Pw,
         static.num_locations,
     )
-    person_sus = params.sus_table[state.health] * params.beta_sus * sus_mult
-    person_inf = params.inf_table[state.health] * params.beta_inf * inf_mult
+    person_sus = (disease_lib.lookup(params.sus_table, state.health)
+                  * params.beta_sus * sus_mult)
+    person_inf = (disease_lib.lookup(params.inf_table, state.health)
+                  * params.beta_inf * inf_mult)
 
     # ---- visit dispatch (all_to_all): route person channels to visits ----
     chans = jnp.stack(
@@ -467,7 +469,7 @@ def dist_day_step(
         # k-th smallest is inside the gathered union, so the threshold is
         # bitwise identical to the single-device full sort.
         us = rng.uniform(params.seed, rng.SEED_CHOICE, day, gpid)
-        sus_ok = params.sus_table[state.health] > 0.0
+        sus_ok = disease_lib.lookup(params.sus_table, state.health) > 0.0
         us = jnp.where(sus_ok, us, 2.0)
         local_small = -jax.lax.top_k(-us, static.seed_topk)[0]
         all_small = jnp.sort(
@@ -484,7 +486,7 @@ def dist_day_step(
         None,
     )
 
-    can_infect = params.sus_table[state.health] > 0.0
+    can_infect = disease_lib.lookup(params.sus_table, state.health) > 0.0
     new_mask = (infected | seeded) & can_infect
     health, dwell = disease_lib.update_health_tables(
         params.cum_trans,
@@ -503,10 +505,14 @@ def dist_day_step(
     new_count = jax.lax.psum(new_mask.sum().astype(jnp.int32), axis)
     cumulative = state.cumulative + new_count
     infectious = jax.lax.psum(
-        (params.inf_table[health] > 0.0).sum().astype(jnp.int32), axis
+        (disease_lib.lookup(params.inf_table, health) > 0.0)
+        .sum().astype(jnp.int32),
+        axis,
     )
     susceptible = jax.lax.psum(
-        (params.sus_table[health] > 0.0).sum().astype(jnp.int32), axis
+        (disease_lib.lookup(params.sus_table, health) > 0.0)
+        .sum().astype(jnp.int32),
+        axis,
     )
     # Summed unsigned before the cross-worker accumulation (see
     # simulator.COUNT_DTYPE).

@@ -117,8 +117,10 @@ def day_step(
         if K2:
             in_iso = day < state.isolated_until
             visit_ok = visit_ok & ~in_iso
-            sym = params.sym_table[state.health] > 0.0
-            detectable = params.inf_table[state.health] > 0.0
+            sym = disease_lib.lookup(params.sym_table, state.health) > 0.0
+            detectable = (
+                disease_lib.lookup(params.inf_table, state.health) > 0.0
+            )
             take_any = jnp.zeros((Pw,), bool)
             for k2 in range(K2):
                 act = params.iv.pa_enabled[k2] & (day >= params.iv.pa_start[k2])
@@ -155,8 +157,10 @@ def day_step(
 
     # ---- per-person epidemiological channels (disease-table lookups) -----
     with _scope("person_channels"):
-        person_sus = params.sus_table[state.health] * params.beta_sus * sus_mult
-        person_inf = params.inf_table[state.health] * params.beta_inf * inf_mult
+        person_sus = (disease_lib.lookup(params.sus_table, state.health)
+                      * params.beta_sus * sus_mult)
+        person_inf = (disease_lib.lookup(params.inf_table, state.health)
+                      * params.beta_inf * inf_mult)
         person_chans = [person_sus, person_inf, visit_ok.astype(jnp.float32)]
         if tracing_on:
             person_chans.append(positives.astype(jnp.float32))
@@ -238,7 +242,7 @@ def day_step(
 
         def with_seeding(_):
             us = rng.uniform(params.seed, rng.SEED_CHOICE, day, gpid)
-            sus_ok = params.sus_table[state.health] > 0.0
+            sus_ok = disease_lib.lookup(params.sus_table, state.health) > 0.0
             us = jnp.where(sus_ok, us, 2.0)
             thresh = topo.seed_threshold(
                 us, params.seed_per_day, static.num_people, static.seed_topk
@@ -253,7 +257,7 @@ def day_step(
         )
 
     with _scope("health_update"):
-        can_infect = params.sus_table[state.health] > 0.0
+        can_infect = disease_lib.lookup(params.sus_table, state.health) > 0.0
         new_mask = (infected | seeded) & can_infect
         health, dwell = disease_lib.update_health_tables(
             params.cum_trans,
@@ -273,10 +277,12 @@ def day_step(
         new_count = topo.psum(new_mask.sum().astype(jnp.int32))
         cumulative = state.cumulative + new_count
         infectious = topo.psum(
-            (params.inf_table[health] > 0.0).sum().astype(jnp.int32)
+            (disease_lib.lookup(params.inf_table, health) > 0.0)
+            .sum().astype(jnp.int32)
         )
         susceptible = topo.psum(
-            (params.sus_table[health] > 0.0).sum().astype(jnp.int32)
+            (disease_lib.lookup(params.sus_table, health) > 0.0)
+            .sum().astype(jnp.int32)
         )
         # Summed unsigned before the cross-worker accumulation (see
         # simulator.COUNT_DTYPE).

@@ -1,4 +1,5 @@
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -64,3 +65,57 @@ def test_dwell_minimum_one_day():
     )
     d2 = np.asarray(d2)
     assert (d2[np.asarray(s2) == m.entry_state] >= 1.0).all()
+
+
+# ---------------------------------------------------------------------------
+# lookup: a per-state table read as a select over the S states, bit for bit
+# the gather ``table[h]`` (negative codes from the end, the rest clamped)
+# ---------------------------------------------------------------------------
+
+MODELS = {"covid": disease.covid_model, "sir": disease.sir_model,
+          "seir": disease.seir_model}
+
+
+def _tables(m, shape):
+    if shape == "rows":
+        return [m.cum_trans]
+    return [m.susceptibility, m.infectivity, m.sym_table, m.dwell_mean_days]
+
+
+def _codes(S, n):
+    edge = [-2**31, -S - 1, -S, -1, S, S + 1, 2**31 - 1]
+    return np.asarray(edge + [i % S for i in range(n)], np.int32)
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint32)
+
+
+@pytest.mark.parametrize("shape", ["column", "rows"])
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_lookup_equals_gather(name, shape):
+    m = MODELS[name]()
+    S = m.num_states
+    h = jnp.asarray(_codes(S, 3 * S))
+    for t in _tables(m, shape):
+        t = jnp.asarray(t)
+        got = jax.jit(disease.lookup)(t, h)
+        assert got.shape == h.shape + t.shape[1:]
+        np.testing.assert_array_equal(_bits(got), _bits(t[h]))
+
+
+@pytest.mark.parametrize("shape", ["column", "rows"])
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_lookup_equals_gather_under_vmap(name, shape):
+    m = MODELS[name]()
+    S, B = m.num_states, 3
+    rng = np.random.default_rng(S)
+    h = jnp.asarray(np.stack([rng.permutation(_codes(S, 4 * S))
+                              for _ in range(B)]))
+    gather = jax.jit(jax.vmap(lambda t, c: t[c]))
+    select = jax.jit(jax.vmap(disease.lookup))
+    for t in _tables(m, shape):
+        # per-scenario tables that differ, -0.0 among them
+        tb = jnp.asarray(np.stack([t * np.float32(1 - b) for b in range(B)]))
+        np.testing.assert_array_equal(_bits(select(tb, h)),
+                                      _bits(gather(tb, h)))

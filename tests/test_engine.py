@@ -576,3 +576,41 @@ def test_named_scopes_change_metadata_only(batch, monkeypatch):
     plain, without = _stripped_hlo(pop, batch, obs)
     assert not any(f"({name})/" in plain for name in day_lib.SCOPES)
     assert with_scopes == without
+
+
+# ---------------------------------------------------------------------------
+# the disease tables are read by a select over the S states: no gather in
+# the day loop takes a table of shape (S,) or (S, S), batched or not (a TPU
+# runs such a gather about one element at a time)
+# ---------------------------------------------------------------------------
+
+
+def _md_tti_arms():
+    tti = lambda name, trace: iv.TestTraceIsolate(
+        name, tests_per_day=15, isolation_days=10, trace=trace,
+        trace_isolation_days=14, start_day=0,
+    )
+    return ScenarioBatch.from_product(
+        interventions={"none": [], "tti": [tti("tti", True)],
+                       "tti-no-trace": [tti("test-isolate", False)]},
+        tau=2e-5, seeds=[11],
+    )
+
+
+@pytest.mark.parametrize("arms", ["classic", "md-tti"])
+def test_day_step_reads_disease_tables_without_gather(pop, batch, arms):
+    from repro.analysis import hlo
+
+    b = batch if arms == "classic" else _md_tti_arms()
+    core = EngineCore(pop, b, layout="local", backend="jnp")
+    B, S = len(b), core.params.sus_table.shape[-1]
+    if arms == "md-tti":
+        assert core.static.pa_slots
+    tables = {(S,), (S, S), (B, S), (B, S, S)}
+    jaxpr = jax.make_jaxpr(core.runner_fn(1, ()))(
+        core.params, core.init_state(), (), core.week, core.route).jaxpr
+    gathers = [tuple(e.invars[0].aval.shape)
+               for j in hlo._iter_jaxprs(jaxpr) for e in j.eqns
+               if e.primitive.name == "gather"]
+    assert gathers, "the walk missed the slot and location gathers"
+    assert not tables & set(gathers), gathers
