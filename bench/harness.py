@@ -239,6 +239,7 @@ class Run:
     peak_bytes: int = 0
     edges_per_chunk: int = 0
     trace: dict = None  # bench.trace.reduce of the traced window
+    reference_s: float = 0.0
     compared: dict = None  # every number bench.reference.compare gives
     checks: dict = None  # the numbers held to a limit, with it
 
@@ -367,7 +368,8 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
     want = stretches(cfg, traffic, raw_twin(pop), seed,
                      cfg.get("dtype", "float32"))
     numbers = compare(prog, want)
-    _log(f"[{name}] reference {time.perf_counter() - t0:.1f}s")
+    run.reference_s = time.perf_counter() - t0
+    _log(f"[{name}] reference {run.reference_s:.1f}s")
     run.compared = numbers
     run.checks = {k: {"value": numbers[k], "limit": cell.limits[k]["limit"]}
                   for k in cell.limits}
@@ -415,7 +417,8 @@ def report(run: Run) -> None:
         "compile_s": run.compile_s, "cache_hits": run.cache_hits,
         "cache_misses": run.cache_misses,
         "window_compiles": run.window_compiles,
-        "edges_per_chunk": run.edges_per_chunk, "compared": run.compared,
+        "edges_per_chunk": run.edges_per_chunk,
+        "reference_s": run.reference_s, "compared": run.compared,
         "teps": run.edges_per_chunk * run.chunks / run.window_s,
     }
     if run.trace is not None:

@@ -18,12 +18,29 @@ One simulated day, per scenario:
    same location with overlapping time, ``i`` and ``j`` active and of
    different people, the pair makes contact when its draw is below the
    location's contact probability. A contact adds
-   ``overlap * sus_i * inf_j`` to person ``i``'s exposure. The pairs are
-   enumerated from the infectious side, block by block, so the work is
-   the number of such pairs and not the square of a location's size.
+   ``overlap * sus_i * inf_j`` to person ``i``'s exposure and, where
+   ``j``'s person tested positive, one to ``i``'s traced count.
 3. Infection where the draw exceeds ``exp(-tau * exposure)``; outbreak
    seeding on the first days; the state machine's timed transitions.
 4. Daily counts, isolation and tracing, then the triggers.
+
+How the pairs are enumerated (:func:`walk_pairs`, :func:`exposure`). A
+day's real visits are sorted by location, so the visits to the location
+of visit ``j`` are one contiguous window: ``wfirst[j]`` to ``wfirst[j] +
+wsize[j] - 1``. The infectious visits are the rows. Each row is put in
+the size class of its window, the least power of two ``W`` that holds it
+(up to ``WIDTH_MAX``; a wider window is walked as several chunks of
+``WIDTH_MAX``), and the rows of one class are taken a block at a time as
+an ``[R, W]`` grid of (row, place in the window): its partner ``i`` is
+``wfirst[j]`` plus the place, and places past ``wsize[j]`` are masked. So
+no pair is searched for, and the work is the pairs, rounded up to a power
+of two per row. A block is taken in two passes. The contact draw needs
+only the two people and the location, so it is made for every pair;
+the overlap and the susceptibility are fetched only for the pairs whose
+draw fell below the contact probability, which a sort brings to the
+front. Per-person disease-table reads are compare-selects over the S
+states (:func:`lookup`), and each location's peak occupancy comes from
+two sorts of the day's arrivals and departures (:func:`_peak_one_day`).
 
 Every draw is the counter hash of ``(seed, stream, day, ids)``, so the
 program and this reference draw the same numbers. What can differ is the
@@ -53,9 +70,15 @@ CONTACT, INFECT, TRANSITION, DWELL, SEED_CHOICE, INIT_ATTR, TEST = (
 _GOLDEN = 0x9E3779B9
 _C1, _C2 = 0x85EBCA6B, 0xC2B2AE35
 
-#: Pairs handled per step of the exposure loop.
-PAIR_BLOCK = 1 << 22
+#: Pairs (row, place in the window) handled per step of the exposure loop.
+PAIR_BLOCK = 1 << 17
+#: The widest window walked in one piece; wider ones are walked in chunks.
+WIDTH_MAX = 1 << 10
+#: Drawn pairs given a closer look per step.
+DRAWN_BLOCK = 1 << 13
 
+#: One day's visits and their windows, as the device holds them.
+DAY_KEYS = ("person", "loc", "start", "end", "wfirst", "wsize")
 STAT_KEYS = ("day", "new_infections", "cumulative", "infectious",
              "susceptible", "contacts", "edges", "tests_used", "isolated",
              "traced")
@@ -141,39 +164,40 @@ def contact_probability(peak, a=5.0, b=40.0, alpha=1000.0):
     return np.where(n <= 2.0, 1.0, np.minimum(p, 1.0)).astype(np.float32)
 
 
-@partial(jax.jit, static_argnums=(0,))
-def _peak_one_day(num_locations, loc, start, end, real):
-    """Peak number of simultaneous visitors per location: +1 at each
-    arrival, -1 at each departure, departures first at equal times."""
-    n = loc.shape[0]
-    locs = jnp.concatenate([loc, loc])
+@jax.jit
+def _peak_one_day(loc, start, end, real, last):
+    """Peak number of simultaneous visitors at each location on one day:
+    +1 at each arrival, -1 at each departure, departures first at equal
+    times. ``last[l]`` is the index of location ``l``'s last event once
+    the day's events are sorted by location, -1 where it has none.
+
+    Each location's arrivals and departures balance, so the running count
+    is 0 before its first event and its peak is the largest running count
+    among its events, which a second sort, by (location, count), puts at
+    its last event."""
+    nowhere = jnp.iinfo(jnp.int32).max  # padding sorts last
+    locs = jnp.where(jnp.concatenate([real, real]),
+                     jnp.concatenate([loc, loc]), nowhere)
     times = jnp.concatenate([start, end])
-    arrive = jnp.concatenate([jnp.ones((n,), jnp.int32),
-                              jnp.zeros((n,), jnp.int32)])
-    ok = jnp.concatenate([real, real])
-    locs = jnp.where(ok, locs, num_locations)  # padding sorts last
-    locs_s, _, arr_s = jax.lax.sort((locs, times, arrive), num_keys=3)
-    step = 2 * arr_s - 1
-    run = jnp.cumsum(step)
-    first = jnp.concatenate([jnp.ones((1,), bool), locs_s[1:] != locs_s[:-1]])
-    seg = jnp.cumsum(first) - 1
-    base = jax.ops.segment_max(jnp.where(first, run - step, -2 ** 30), seg,
-                               num_segments=2 * n)
-    rel = run - base[seg]
-    peak_seg = jax.ops.segment_max(rel, seg, num_segments=2 * n)
-    loc_seg = jax.ops.segment_max(locs_s, seg, num_segments=2 * n)
-    out = jnp.zeros((num_locations + 1,), jnp.int32)
-    return out.at[loc_seg].max(peak_seg, mode="drop")[:num_locations]
+    arrive = jnp.concatenate([jnp.ones_like(loc), jnp.zeros_like(loc)])
+    locs_s, _, arrive_s = jax.lax.sort((locs, times, arrive), num_keys=3)
+    _, run = jax.lax.sort((locs_s, jnp.cumsum(2 * arrive_s - 1)), num_keys=2)
+    return jnp.where(last >= 0, run[jnp.maximum(last, 0)], 0)
 
 
-def peak_occupancy(twin: Twin) -> np.ndarray:
-    peak = np.zeros((twin.num_locations,), np.int32)
-    for (person, loc, start, end), n in zip(twin.week, twin.num_real):
-        real = np.arange(len(person)) < n
-        peak = np.maximum(peak, np.asarray(_peak_one_day(
-            twin.num_locations, jnp.asarray(loc), jnp.asarray(start),
-            jnp.asarray(end), jnp.asarray(real))))
-    return peak
+def locations_of_day(loc: np.ndarray, n: int, num_locations: int):
+    """For one day's visits (real first, sorted by location): each visit's
+    window, the index of the first real visit to its location and their
+    number (0 and 0 for padding), and each location's ``last`` event for
+    ``_peak_one_day``."""
+    count = np.bincount(loc[:n], minlength=num_locations)
+    end = np.cumsum(count)
+    wfirst = np.zeros(len(loc), np.int32)
+    wsize = np.zeros(len(loc), np.int32)
+    wfirst[:n] = (end - count)[loc[:n]]
+    wsize[:n] = count[loc[:n]]
+    last = np.where(count > 0, 2 * end - 1, -1).astype(np.int32)
+    return wfirst, wsize, last
 
 
 def check_twin(twin: Twin):
@@ -184,6 +208,75 @@ def check_twin(twin: Twin):
             raise ValueError("visits must be real first, then padding")
         if n > 1 and (np.diff(loc[:n]) < 0).any():
             raise ValueError("real visits must be sorted by location")
+
+
+def size_classes(max_window: int, width_max: int = WIDTH_MAX) -> tuple:
+    """``(width, chunks)`` of each size class of windows: widths 1, 2, 4,
+    ... up to the first that holds ``max_window`` visits or is
+    ``width_max``, one chunk each; then, if some window is wider than
+    ``width_max``, a class that walks each window as that many chunks of
+    ``width_max``."""
+    if width_max & (width_max - 1):
+        raise ValueError("width_max must be a power of two")
+    classes, w = [], 1
+    while w < min(max_window, width_max):
+        classes.append((w, 1))
+        w *= 2
+    classes.append((w, 1))
+    if max_window > width_max:
+        classes.append((width_max, -(-max_window // width_max)))
+    return tuple(classes)
+
+
+def walk_pairs(classes: tuple, wfirst, wsize, is_row, visit, carry):
+    """Hand every pair ``(j, i)`` of a row ``j`` (``is_row``) and a visit
+    ``i`` in its window, ``i == j`` included, to ``visit(carry, j, i,
+    ok)``, a block at a time: ``j`` is an ``[R]`` array of rows, ``i`` and
+    ``ok`` are ``[R, W]``, and only the entries where ``ok`` holds are
+    pairs. Returns the last carry.
+
+    The rows are sorted by size class (``size_classes``) with one sort of
+    ``class * V + visit``. A class of width ``W`` and ``T`` chunks gives
+    each of its rows ``T`` pieces of ``W`` places, and is walked in blocks
+    of ``R = PAIR_BLOCK // W`` pieces."""
+    V = is_row.shape[0]
+    C = len(classes)
+    cls = sum((wsize > w).astype(jnp.int32) for w, _ in classes[:-1])
+    key = jnp.sort(jnp.where(is_row, cls, C) * V
+                   + jnp.arange(V, dtype=jnp.int32))
+    offset = jnp.searchsorted(key, jnp.arange(C + 1, dtype=jnp.int32) * V)
+    rows = key % V
+    for c, (W, T) in enumerate(classes):
+        R = max(PAIR_BLOCK // W, 1)
+        pieces = (offset[c + 1] - offset[c]) * T
+        place = jnp.arange(W, dtype=jnp.int32)[None, :]
+
+        def block(b, carry, c=c, W=W, T=T, R=R, pieces=pieces, place=place):
+            k = b * R + jnp.arange(R, dtype=jnp.int32)
+            j = rows[jnp.minimum(offset[c] + k // T, V - 1)]
+            at = (k % T)[:, None] * W + place
+            ok = (k < pieces)[:, None] & (at < wsize[j][:, None])
+            return visit(carry, j, jnp.where(ok, wfirst[j][:, None] + at, 0),
+                         ok)
+
+        carry = jax.lax.fori_loop(0, (pieces + R - 1) // R, block, carry)
+    return carry
+
+
+def take(x, idx):
+    """``x[idx]`` through a one-dimensional index, which a TPU gathers
+    faster than the same indices in two."""
+    return x[idx.reshape(-1)].reshape(idx.shape)
+
+
+def lookup(table, h):
+    """``table[h]`` for state codes ``h`` in ``0..S-1``, as compare-selects
+    over the S states: a TPU gathers from a small table at the cost of
+    many element-wise passes."""
+    out = jnp.broadcast_to(table[0], h.shape)
+    for s in range(1, table.shape[0]):
+        out = jnp.where(h == s, table[s], out)
+    return out
 
 
 def disease_tables(d: dict) -> dict:
@@ -286,6 +379,7 @@ class Structure:
     tti: tuple  # (trace?, ...)
     seed_per_day: int
     dtype: str
+    classes: tuple = ()  # size_classes of the twin's windows
 
 
 def union_slots(arms: list) -> tuple:
@@ -367,6 +461,74 @@ def scenario_inputs(cfg: dict, traffic: dict, twin: Twin, seed: int):
 # ---------------------------------------------------------------------------
 
 
+def exposure(st: Structure, day: dict, prob, sus_p, inf_p, loc_open,
+             positive, seed, d):
+    """Step 2 of a day: each person's exposure, the day's contacts, and
+    each person's contacts with people who tested positive (``trc``, kept
+    where the structure has test-trace-isolate slots).
+
+    ``day`` holds one day's visits (``person``, ``loc``, ``start``,
+    ``end``) and their windows (``wfirst``, ``wsize``); ``sus_p`` and
+    ``inf_p`` are each person's susceptibility and infectivity where they
+    may go out, else 0. A visit is active where its person may go out and
+    its location is open; the two visits of a pair share the location.
+
+    A pair's contact draw depends on its two people and the location
+    only, so each block of pairs is taken in two passes: the draw for
+    every pair, then a closer look (overlap, susceptibility) at the pairs
+    whose draw fell below the contact probability, sorted to the front
+    and taken ``DRAWN_BLOCK`` at a time."""
+    f = jnp.dtype(st.dtype)
+    P = st.num_people
+    vp, vl = day["person"], day["loc"]
+    start_f, end_f = day["start"].astype(f), day["end"].astype(f)
+    real = vp >= 0
+    is_row = real & (inf_p[jnp.maximum(vp, 0)] > 0)
+
+    def meet(carry, j, i, ok):
+        lj, pj = vl[j], vp[j]
+        pi = take(vp, i)
+        lo = jnp.minimum(pi, pj[:, None]).astype(jnp.uint32)
+        hi = jnp.maximum(pi, pj[:, None]).astype(jnp.uint32)
+        u = draw(seed, CONTACT, d, lo, hi, lj[:, None].astype(jnp.uint32))
+        drawn = (ok & (pi != pj[:, None]) & loc_open[lj][:, None]
+                 & (u.astype(f) < prob[lj][:, None].astype(f)))
+        n = ok.size
+        slots = jnp.sort(jnp.where(drawn, jnp.arange(n, dtype=jnp.int32)
+                                   .reshape(ok.shape), n).reshape(-1))
+        i, pi = i.reshape(-1), pi.reshape(-1)
+        start_j, end_j, inf_j = start_f[j], end_f[j], inf_p[pj]
+        pos_j = positive[pj]
+        W = ok.shape[1]
+
+        def closer(k, carry):
+            acc, cnt, trc = carry
+            s = jax.lax.dynamic_slice(slots, (k * DRAWN_BLOCK,),
+                                      (DRAWN_BLOCK,))
+            live = s < n
+            s = jnp.where(live, s, 0)
+            r, ii, p = s // W, i[s], pi[s]
+            overlap = jnp.maximum(jnp.minimum(end_f[ii], end_j[r])
+                                  - jnp.maximum(start_f[ii], start_j[r]), 0)
+            sus_i = sus_p[jnp.maximum(p, 0)]
+            contact = live & (overlap > 0) & (sus_i > 0)
+            rho = jnp.where(contact, overlap * sus_i * inf_j[r], 0)
+            acc = acc.at[jnp.where(contact, p, P)].add(rho, mode="drop")
+            cnt = cnt + contact.sum().astype(jnp.uint32)
+            if st.tti:
+                trc = trc.at[jnp.where(contact & pos_j[r], p, P)].add(
+                    1, mode="drop")
+            return acc, cnt, trc
+
+        m = drawn.sum(dtype=jnp.int32)
+        return jax.lax.fori_loop(0, (m + DRAWN_BLOCK - 1) // DRAWN_BLOCK,
+                                 closer, carry)
+
+    return walk_pairs(st.classes, day["wfirst"], day["wsize"], is_row, meet,
+                      (jnp.zeros((P,), f), jnp.zeros((), jnp.uint32),
+                       jnp.zeros((P,), jnp.int32)))
+
+
 def init_state(structure: Structure, tables: dict, B: int) -> dict:
     P, K = structure.num_people, len(structure.classic)
     return {
@@ -392,10 +554,6 @@ def day_step(st: Structure, data, common, sc, s, d):
     pid = jnp.arange(P, dtype=jnp.uint32)
     h = s["health"]
     w = d % DAYS_PER_WEEK
-    vp, vl = data["person"][w], data["loc"][w]
-    vs, ve = data["start"][w], data["end"][w]
-    first, size = data["first"][w], data["size"][w]
-    prob = data["prob"]
 
     # 1. interventions, classic slots
     visit_ok = jnp.ones((P,), bool)
@@ -417,8 +575,8 @@ def day_step(st: Structure, data, common, sc, s, d):
         sus_mult = jnp.where(vacc & sc["people"][k], sc["factor"][k], 1.0)
 
     # 1b. test-trace-isolate: capacity-limited testing
-    sym = tab["sym"][h] > 0
-    detectable = tab["inf"][h] > 0
+    sym = lookup(tab["sym"], h) > 0
+    detectable = lookup(tab["inf"], h) > 0
     in_iso = d < s["isolated_until"]
     visit_ok = visit_ok & ~in_iso if st.tti else visit_ok
     takes, tests_used = [], jnp.zeros((), jnp.int32)
@@ -437,55 +595,19 @@ def day_step(st: Structure, data, common, sc, s, d):
     positive = take_any & detectable
 
     # 2. exposure over co-located, overlapping visits
-    p_sus = (tab["sus"][h].astype(f) * data["beta_sus"].astype(f)
-             * sus_mult.astype(f))
-    p_inf = tab["inf"][h].astype(f) * data["beta_inf"].astype(f)
-    real = vp >= 0
-    q = jnp.maximum(vp, 0)
-    active = real & visit_ok[q] & loc_open[vl]
-    sus_v = jnp.where(active, p_sus[q], 0)
-    inf_v = jnp.where(active, p_inf[q], 0)
-    src_v = active & positive[q]
-    n_j = jnp.where(inf_v > 0, size[vl], 0)
-    cum = jnp.cumsum(n_j)  # pairs are counted from the infectious side
-    total = cum[-1]
-    start_f, end_f = vs.astype(f), ve.astype(f)
-
-    def block(b, carry):
-        acc, cnt, trc = carry
-        k = b * PAIR_BLOCK + jnp.arange(PAIR_BLOCK, dtype=jnp.int32)
-        ok = k < total
-        j = jnp.minimum(jnp.searchsorted(cum, k, side="right"),
-                        vp.shape[0] - 1)
-        i = first[vl[j]] + (k - (cum[j] - n_j[j]))
-        i = jnp.where(ok, i, 0)
-        overlap = jnp.maximum(jnp.minimum(end_f[i], end_f[j])
-                              - jnp.maximum(start_f[i], start_f[j]), 0)
-        pi, pj = vp[i], vp[j]
-        lo = jnp.minimum(pi, pj).astype(jnp.uint32)
-        hi = jnp.maximum(pi, pj).astype(jnp.uint32)
-        u = draw(seed, CONTACT, d, lo, hi, vl[j].astype(jnp.uint32))
-        contact = (ok & (pi != pj) & (overlap > 0) & (sus_v[i] > 0)
-                   & (u.astype(f) < prob[vl[j]].astype(f)))
-        rho = jnp.where(contact, overlap * sus_v[i] * inf_v[j], 0)
-        who = jnp.where(contact, pi, P)
-        acc = acc.at[who].add(rho, mode="drop")
-        cnt = cnt + contact.sum().astype(jnp.uint32)
-        trc = trc.at[jnp.where(contact & src_v[j], pi, P)].add(
-            1, mode="drop")
-        return acc, cnt, trc
-
-    nblocks = (total + PAIR_BLOCK - 1) // PAIR_BLOCK
-    acc, contacts, trc = jax.lax.fori_loop(
-        0, nblocks, block,
-        (jnp.zeros((P,), f), jnp.zeros((), jnp.uint32),
-         jnp.zeros((P,), jnp.int32)))
+    sus_p = jnp.where(visit_ok, lookup(tab["sus"], h).astype(f)
+                      * data["beta_sus"].astype(f) * sus_mult.astype(f), 0)
+    inf_p = jnp.where(visit_ok, lookup(tab["inf"], h).astype(f)
+                      * data["beta_inf"].astype(f), 0)
+    today = {k: data[k][w] for k in DAY_KEYS}
+    acc, contacts, trc = exposure(st, today, data["prob"], sus_p, inf_p,
+                                  loc_open, positive, seed, d)
 
     # 3. infection, seeding, state machine
     A = acc * common["tau"].astype(f)
     u_inf = draw(seed, INFECT, d, pid).astype(f)
     infected = (A > 0) & (u_inf > jnp.exp(-A))
-    sus_ok = tab["sus"][h] > 0
+    sus_ok = lookup(tab["sus"], h) > 0
     us = jnp.where(sus_ok, draw(seed, SEED_CHOICE, d, pid).astype(f), 2)
     thresh = kth_smallest(us, max(min(st.seed_per_day, P), 1))
     seeded = ((d < common["seed_days"]) & (us <= thresh) & sus_ok
@@ -493,7 +615,8 @@ def day_step(st: Structure, data, common, sc, s, d):
     new = (infected | seeded) & sus_ok
 
     u_t = draw(seed, TRANSITION, d, pid)
-    nxt = jnp.sum(tab["cum"][h] < u_t[:, None], axis=-1).astype(jnp.int32)
+    nxt = sum((lookup(tab["cum"][:, t], h) < u_t).astype(jnp.int32)
+              for t in range(tab["cum"].shape[1]))
     # The countdown is bookkeeping of the float32 state, not arithmetic
     # whose precision the control lowers.
     dwell_after = s["dwell"] - 1
@@ -501,7 +624,7 @@ def day_step(st: Structure, data, common, sc, s, d):
     h_t = jnp.where(timed, nxt, h)
     h_new = jnp.where(new, tab["entry"], h_t)
     changed = new | (timed & (h_new != h))
-    mean = tab["dwell"][h_new]
+    mean = lookup(tab["dwell"], h_new)
     fresh = -mean.astype(f) * jnp.log(draw(seed, DWELL, d, pid).astype(f))
     fresh = jnp.maximum(fresh, 1).astype(jnp.float32)
     fresh = jnp.where(mean >= ABSORBING_DWELL, ABSORBING_DWELL, fresh)
@@ -513,8 +636,9 @@ def day_step(st: Structure, data, common, sc, s, d):
         "day": d,
         "new_infections": n_new,
         "cumulative": cumulative,
-        "infectious": (tab["inf"][h_new] > 0).sum().astype(jnp.int32),
-        "susceptible": (tab["sus"][h_new] > 0).sum().astype(jnp.int32),
+        "infectious": (lookup(tab["inf"], h_new) > 0).sum().astype(jnp.int32),
+        "susceptible": (lookup(tab["sus"], h_new) > 0).sum().astype(
+            jnp.int32),
         "contacts": contacts,
         "edges": contacts,
         "tests_used": tests_used,
@@ -571,32 +695,33 @@ class Reference:
             raise ValueError("the reference draws contacts by day only")
         check_twin(twin)
         structure, stacked, common = scenario_inputs(cfg, traffic, twin, seed)
-        self.structure = dataclasses.replace(structure, dtype=dtype)
+        L = twin.num_locations
+        week, peak, widest = [], jnp.zeros((L,), jnp.int32), 1
+        for (person, loc, start, end), n in zip(twin.week, twin.num_real):
+            wfirst, wsize, last = locations_of_day(loc, n, L)
+            day = {k: jnp.asarray(x) for k, x in zip(
+                DAY_KEYS, (person, loc, start, end, wfirst, wsize))}
+            peak = jnp.maximum(peak, _peak_one_day(
+                day["loc"], day["start"], day["end"], day["person"] >= 0,
+                jnp.asarray(last)))
+            widest = max(widest, int(wsize.max()))
+            week.append(day)
+        classes = size_classes(widest)
+        V = len(twin.week[0][0])
+        if (len(classes) + 1) * V >= 2 ** 31:
+            raise ValueError("too many visits a day for the row sort's key")
+        self.structure = dataclasses.replace(structure, dtype=dtype,
+                                             classes=classes)
         self.B = len(cfg["arms"])
         self.scen = [jax.tree.map(lambda x: jnp.asarray(x[b]), stacked)
                      for b in range(self.B)]
         self.common = jax.tree.map(jnp.asarray, common)
         self.tables = common["tables"]
-        prob = contact_probability(peak_occupancy(twin))
-        L = twin.num_locations
-        firsts, sizes = [], []
-        for (_, loc, _, _), n in zip(twin.week, twin.num_real):
-            lo = np.searchsorted(loc[:n], np.arange(L), side="left")
-            hi = np.searchsorted(loc[:n], np.arange(L), side="right")
-            firsts.append(lo.astype(np.int32))
-            sizes.append((hi - lo).astype(np.int32))
-        cols = list(zip(*twin.week))
-        self.data = {
-            "person": jnp.asarray(np.stack(cols[0])),
-            "loc": jnp.asarray(np.stack(cols[1])),
-            "start": jnp.asarray(np.stack(cols[2])),
-            "end": jnp.asarray(np.stack(cols[3])),
-            "first": jnp.asarray(np.stack(firsts)),
-            "size": jnp.asarray(np.stack(sizes)),
-            "prob": jnp.asarray(prob),
-            "beta_sus": jnp.asarray(twin.beta_sus, jnp.float32),
-            "beta_inf": jnp.asarray(twin.beta_inf, jnp.float32),
-        }
+        self.data = {k: jnp.stack([d[k] for d in week]) for k in DAY_KEYS}
+        self.data.update(
+            prob=jnp.asarray(contact_probability(np.asarray(peak))),
+            beta_sus=jnp.asarray(twin.beta_sus, jnp.float32),
+            beta_inf=jnp.asarray(twin.beta_inf, jnp.float32))
 
     def init_state(self) -> dict:
         return init_state(self.structure, self.tables, self.B)
