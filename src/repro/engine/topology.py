@@ -113,16 +113,22 @@ class Topology:
 
     def combine_many(self, route, pid, active, accs, num_people_local: int):
         """Channel-stacked :meth:`combine`: ``accs`` is ``(V_local, C)``,
-        returns ``(P_local, C)``. Each channel folds independently in the
-        same per-visit order as the single-channel combine, so channel 0 of
-        the result is bitwise identical to ``combine`` of ``accs[:, 0]`` —
-        the traced-contact halo rides the exposure halo for free.
+        returns ``(P_local, C)``, channel ``c`` being ``combine`` of
+        ``accs[:, c]`` bit for bit.
+
+        Each channel is summed on its own, never as one segment sum of
+        ``(V, C)`` values. Under the scenario vmap that sum is a scatter
+        whose window spans the scenario and channel axes, and XLA on the
+        TPU lowers such a channel-windowed scatter to a plain scatter
+        fusion, not to the sort and sorted reduction it gives a
+        single-channel one: on the md twin the two-channel scatter took
+        6.9 times one channel's sort-lowered combine (PERF.md).
+        ``tests/test_engine.py`` guards the lowering.
         """
-        del route
-        return jax.ops.segment_sum(
-            jnp.where(active[:, None], accs, 0.0),
-            jnp.maximum(pid, 0),
-            num_segments=num_people_local,
+        return jnp.stack(
+            [self.combine(route, pid, active, accs[:, c], num_people_local)
+             for c in range(accs.shape[-1])],
+            axis=-1,
         )
 
     # -- global order statistic for outbreak seeding ----------------------

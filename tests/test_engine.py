@@ -12,6 +12,7 @@ in-scan observable path on sharded topologies.
 """
 
 import dataclasses
+import re
 
 import numpy as np
 import jax
@@ -268,6 +269,57 @@ def test_local_topology_identity_collectives():
     active = pid >= 0
     back = topo.combine(None, pid, active, acc, 3)
     np.testing.assert_array_equal(back, [1.0, 4.0, 2.0])
+
+
+def _combine_many_inputs(C, integer):
+    """A shared visit schedule (repeated people, ``-1`` padding) and
+    per-scenario ``active``/``accs`` for B = 3, as ``run_days`` vmaps the
+    combine."""
+    rng = np.random.default_rng(100 + C)
+    pid = np.asarray([3, 0, -1, 3, 5, 1, 3, -1, 0, 6, 2, 5, 3, -1, 4, 0,
+                      1, 6, 6, 2, -1, 3, 5], np.int32)
+    B, V = 3, pid.shape[0]
+    active = (rng.random((B, V)) < 0.75) & (pid >= 0)
+    if integer:
+        accs = rng.integers(0, 1000, (B, V, C)).astype(np.float32)
+    else:
+        accs = rng.standard_normal((B, V, C)).astype(np.float32) * 1e-3
+    return jnp.asarray(pid), jnp.asarray(active), jnp.asarray(accs)
+
+
+def _vmapped(fn, pid, num_people):
+    return jax.jit(jax.vmap(lambda a, x: fn(None, pid, a, x, num_people)))
+
+
+@pytest.mark.parametrize("C", [1, 2, 3])
+def test_local_combine_many_is_per_channel_combine(C):
+    topo, P = LocalTopology(), 7
+    for integer in (False, True):
+        pid, active, accs = _combine_many_inputs(C, integer)
+        many = np.asarray(_vmapped(topo.combine_many, pid, P)(active, accs))
+        assert many.shape == (3, P, C)
+        one = _vmapped(topo.combine, pid, P)
+        for c in range(C):
+            np.testing.assert_array_equal(
+                many[:, :, c], np.asarray(one(active, accs[:, :, c])))
+        if integer:
+            want = np.zeros((3, P, C), np.float64)
+            for b in range(3):
+                np.add.at(want[b], np.maximum(np.asarray(pid), 0),
+                          np.asarray(accs[b]) * np.asarray(active[b])[:, None])
+            np.testing.assert_array_equal(many, want.astype(np.float32))
+
+
+@pytest.mark.parametrize("C", [1, 2, 3])
+def test_local_combine_many_lowers_one_scatter_per_channel(C):
+    # A scatter whose window spans the channel axis misses XLA's sort
+    # lowering on the TPU; each channel's scatter must window the scenario
+    # axis alone, as the single-channel combine's does.
+    pid, active, accs = _combine_many_inputs(C, False)
+    text = _vmapped(LocalTopology().combine_many, pid, 7).lower(
+        active, accs).as_text()
+    windows = re.findall(r"update_window_dims = \[([^\]]*)\]", text)
+    assert windows == ["0"] * C
 
 
 def test_local_seed_threshold_matches_sort():
